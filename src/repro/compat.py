@@ -1,150 +1,74 @@
-"""Version-compat layer: one import site for every jax API whose home or
-signature moved between 0.4.x and 0.5+.
+"""One import site for the jax APIs the repo builds on (jax 0.9).
 
 Everything in the repo that needs ``shard_map``, mesh construction, or the
-varying-axes (vma) machinery goes through this module, so the codebase runs
-unchanged on jax 0.4.37 (no ``jax.shard_map``, no ``jax.sharding.AxisType``,
-no ``jax.typeof``/``lax.pcast``) and on 0.5+/0.8+ where those are canonical.
+varying-axes (vma) machinery goes through this module, so a future move of
+any of them is a one-file change.
 
 Exports:
   shard_map(f, *, mesh, in_specs, out_specs, check_vma=True)
-      Top-level ``jax.shard_map`` when available; otherwise
-      ``jax.experimental.shard_map.shard_map`` with ``check_vma`` translated
-      to the old ``check_rep`` keyword.
-  make_mesh(shape, axes)
-      ``jax.make_mesh`` with explicit Auto axis types when the installed jax
-      has ``AxisType``; plain ``jax.make_mesh`` (or a raw ``Mesh``) otherwise.
+      ``jax.shard_map``.
+  make_mesh(shape, axes, *, devices=None)
+      ``jax.make_mesh`` with explicit Auto axis types.
   vma_of(x) / pvary(x, axes)
-      Read / extend an array's varying-axes set. On jax without the vma
-      system these degrade to ``frozenset()`` / identity, which is exactly
-      the old semantics (everything implicitly varying, nothing tracked).
+      Read / extend an array's varying-axes set.
+  replicated_psum(x, axes)
+      ``lax.psum`` for terminal reductions (loss totals, normalizers).
   psum_scatter / all_gather
       Keyword-stable wrappers over the ``jax.lax`` collectives.
   axis_index / dynamic_update_slice / dynamic_slice / fori_loop
       Re-exports of the non-collective lax helpers the app layer uses, so
       application code never imports ``jax.lax`` directly (grep-enforced).
   HAS_VMA
-      True when the installed jax tracks varying axes in avals.
+      Always True: the supported jax tracks varying axes in avals. Kept
+      for the pre-vma gradient-sync path that still branches on it.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-import numpy as np
 from jax import lax
-
-# --------------------------------------------------------------- shard_map
-try:  # jax >= 0.6: top-level export, check_vma keyword
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x / 0.5.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep")
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` signature on every supported jax version.
-
-    On vma-capable jax (0.5+), ``check_vma`` is passed through: the
-    varying-axes machinery both checks out_specs replication and lets
-    autodiff insert the gradient psums for replicated leaves.
-
-    On pre-vma jax (0.4.x), the old ``check_rep`` checker cannot see through
-    a ``value_and_grad`` inside the body (replication is not part of avals),
-    so ``check_vma=True`` would reject valid programs. It therefore degrades
-    to ``check_rep=False``; gradient correctness for replicated params is
-    restored explicitly by ``repro.runtime.trainer.sync_replicated_grads``
-    (a no-op when HAS_VMA is True).
-    """
-    if _CHECK_KW == "check_rep":
-        kwargs = {"check_rep": False}
-    else:
-        kwargs = {"check_vma": check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """``jax.shard_map``. With ``check_vma`` the varying-axes machinery both
+    checks out_specs replication and lets autodiff insert the gradient psums
+    for replicated leaves."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-# -------------------------------------------------------------------- mesh
-try:
-    from jax.sharding import AxisType as _AxisType
-except ImportError:
-    _AxisType = None
-
-
-def make_mesh(shape, axes):
-    """Device mesh of ``shape`` over ``axes``, Auto-typed where that exists."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.sharding import Mesh
-    ndev = int(np.prod(shape))
-    return Mesh(np.asarray(jax.devices()[:ndev]).reshape(shape), axes)
+def make_mesh(shape, axes, *, devices=None):
+    """Device mesh of ``shape`` over ``axes`` (on ``devices``, default
+    every visible device), Auto-typed."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 # ---------------------------------------------------------- varying axes
-HAS_VMA: bool = hasattr(jax, "typeof") and (
-    hasattr(lax, "pvary") or hasattr(lax, "pcast"))
+HAS_VMA: bool = True
 
 
 def vma_of(x) -> frozenset:
-    """The varying-axes set of ``x`` (empty when jax doesn't track vma)."""
-    if not HAS_VMA:
-        return frozenset()
-    return getattr(jax.typeof(x), "vma", frozenset())
+    """The varying-axes set of ``x`` (empty outside shard_map)."""
+    return jax.typeof(x).vma
 
 
 def pvary(x, axes):
-    """Mark ``x`` varying over ``axes``; identity on pre-vma jax."""
+    """Mark ``x`` varying over ``axes``."""
     axes = tuple(axes)
-    if not axes or not HAS_VMA:
+    if not axes:
         return x
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
     return lax.pcast(x, axes, to="varying")
 
 
 # -------------------------------------------------------- lax collectives
-import functools
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _terminal_psum(x, axes):
-    return lax.psum(x, axes)
-
-
-def _terminal_psum_fwd(x, axes):
-    return lax.psum(x, axes), None
-
-
-def _terminal_psum_bwd(axes, _, ct):
-    return (ct,)
-
-
-_terminal_psum.defvjp(_terminal_psum_fwd, _terminal_psum_bwd)
-
-
 def replicated_psum(x, axes):
     """psum for *terminal* reductions: ones whose output is consumed only by
     group-replicated compute (loss totals, logsumexp/normalizer denominators).
-
-    On vma-tracking jax this is plain ``lax.psum`` -- the varying-axes
-    autodiff transposes it to the identity-shaped pvary, which is exact. On
-    pre-vma jax, ``lax.psum`` transposes to another psum (the old
-    psum-as-psum+pbroadcast convention): correct when cotangents arrive as
-    per-shard partials from sharded downstream use, but a terminal
-    reduction's cotangent is replicated, so that convention over-counts by
-    the group size. A custom_vjp with identity backward restores the exact
-    gradient there.
-    """
-    if HAS_VMA:
-        return lax.psum(x, axes)
-    return _terminal_psum(x, axes if isinstance(axes, str) else tuple(axes))
+    The varying-axes autodiff transposes it to the identity-shaped pvary,
+    which is exact. Models call this instead of ``lax.psum`` (which the CI
+    import grep forbids there) to mark the reduction as terminal."""
+    return lax.psum(x, axes)
 
 
 def psum_scatter(x, axis_name, *, scatter_dimension: int = 0,

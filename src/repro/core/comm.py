@@ -442,6 +442,11 @@ class Communicator:
     def all_reduce(self, x: Array, *, op: str = "add",
                    algorithm: str | None = None) -> Array:
         if self.group_size == 1:
+            # identity on values, but typed invariant over the group like
+            # the multi-member flow, so replicated consumers (loss totals,
+            # softmax normalizers) see the same varying axes at any size
+            if compat.vma_of(x) & set(self.ax):
+                return _REDUCERS[op][0](x, self.ax)
             return x
         return self._dispatch("all_reduce", x, algorithm=algorithm, op=op)
 

@@ -32,24 +32,30 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     lw = lw_ref[0].astype(jnp.float32)        # (C, K) log decays (<= 0)
     u = u_ref[0].astype(jnp.float32)          # (1, K) bonus
 
-    cum = jnp.cumsum(lw, axis=0)              # inclusive
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul:
+    # the TPU kernel compiler has no cumsum lowering
+    tri = jnp.where(s_idx <= t_idx, 1.0, 0.0)
+    cum = jax.lax.dot(tri, lw, precision=jax.lax.Precision.HIGHEST)
     # cross-chunk: o_cross[t] = (r_t * prod_{i<t} w) @ S0
     qd = r * jnp.exp(cum - lw)
     o_cross = qd @ s_ref[...]
     # intra-chunk: A[t,s] = <r_t e^{cum_t - l_t}, k_s e^{-cum_s}> for s < t
     kd = k * jnp.exp(-cum)
     A = qd @ kd.T                             # (C, C)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     A = jnp.where(s_idx < t_idx, A, 0.0)
     diag = jnp.sum(r * u * k, axis=1)         # bonus, s == t
     o = o_cross + A @ v + diag[:, None] * v
     o_ref[0] = o.astype(o_ref.dtype)
 
     # state update: S <- diag(e^{tot}) S + sum_s e^{tot - cum_s} k_s v_s^T
-    tot = cum[-1]
-    kw = k * jnp.exp(tot[None] - cum)
-    s_ref[...] = jnp.exp(tot)[:, None] * s_ref[...] + kw.T @ v
+    # (the chunk's total decay as a row and as a column: reductions, since
+    # the kernel compiler lowers neither cum[-1] nor a 1-D relayout)
+    tot = jnp.sum(lw, axis=0, keepdims=True)               # (1, K)
+    tot_col = jnp.sum(lw.T, axis=1, keepdims=True)         # (K, 1)
+    kw = k * jnp.exp(tot - cum)
+    s_ref[...] = jnp.exp(tot_col) * s_ref[...] + kw.T @ v
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

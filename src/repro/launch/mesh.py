@@ -5,9 +5,9 @@ The production target is TPU v5e: one pod = a 16x16 ICI-connected slice
 ``make_production_mesh`` is a function (never a module-level constant) so that
 importing this module never touches jax device state.
 
-Mesh construction is version-sensitive (``AxisType`` only exists on jax
-0.5+), so it lives in :mod:`repro.compat`; this module re-exports it so all
-launch-path callers keep their import site.
+Mesh construction (explicit ``AxisType``) lives in :mod:`repro.compat`;
+this module re-exports it so all launch-path callers keep their import
+site.
 """
 from __future__ import annotations
 

@@ -10,7 +10,35 @@ config on the production mesh (``--production`` / ``--multipod``).
 from __future__ import annotations
 
 import argparse
-import dataclasses
+
+
+def train_topology(cfg, *, global_batch: int, smoke: bool = False,
+                   devices=None):
+    """``(cfg, topo)`` this launcher trains on over ``devices`` (default:
+    every visible device): the model axis takes ``min(cfg.model_parallel,
+    n)`` of them (1 under ``smoke``), data parallelism the rest, and the
+    config's model-parallel split is cut to match."""
+    import jax
+    from repro.launch.mesh import make_mesh
+    from repro.models.topology import build_topology
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
+    mp = 1 if smoke else min(cfg.model_parallel, n)
+    cfg = cfg.with_model_parallel(mp)
+    mesh = make_mesh((n // mp, mp), ("data", "model"), devices=devices)
+    return cfg, build_topology(cfg, mesh, global_batch=global_batch)
+
+
+def init_train_state(cfg, topo, tc, *, seed: int = 0):
+    """Random parameters from ``seed`` and zero optimizer state, every leaf
+    created in place on its cube sharding."""
+    import jax
+    from repro.models.params import init_params, param_structs
+    from repro.runtime.trainer import init_opt_state
+    shardings = jax.tree.map(lambda s: s.sharding, param_structs(cfg, topo))
+    params = jax.jit(lambda: init_params(cfg, topo, seed),
+                     out_shardings=shardings)()
+    return params, init_opt_state(cfg, topo, tc)
 
 
 def main():
@@ -31,38 +59,33 @@ def main():
     ap.add_argument("--fp32-moments", action="store_true")
     args = ap.parse_args()
 
-    import jax
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from repro import configs
     from repro.checkpoint.manager import CheckpointManager
     from repro.data.pipeline import DataConfig, TokenStream
-    from repro.launch.mesh import make_mesh, make_production_mesh
-    from repro.models.params import init_params, param_specs
+    from repro.launch.mesh import make_production_mesh
+    from repro.models.params import param_specs
     from repro.models.topology import build_topology
     from repro.optim import adamw
-    from repro.runtime.trainer import (
-        Trainer, TrainConfig, init_opt_state, make_train_step,
-        input_batch_specs, opt_specs)
+    from repro.runtime.trainer import Trainer, TrainConfig, opt_specs
 
     cfg = configs.get(args.arch)
     if args.smoke:
         cfg = cfg.scaled_for_smoke()
     if args.production or args.multipod:
         mesh = make_production_mesh(multi_pod=args.multipod)
+        topo = build_topology(cfg, mesh, global_batch=args.batch)
     else:
-        n = len(jax.devices())
-        mp = min(cfg.model_parallel, n)
-        if args.smoke:
-            mp = 1
-        mesh = make_mesh((n // mp, mp), ("data", "model"))
-    topo = build_topology(cfg, mesh, global_batch=args.batch)
+        cfg, topo = train_topology(cfg, global_batch=args.batch,
+                                   smoke=args.smoke)
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
           f"cube={topo.cube.describe()}")
 
     tc = TrainConfig(lr=args.lr, warmup=args.warmup,
                      total_steps=args.steps,
                      adamw=adamw.AdamWConfig(use_8bit=not args.fp32_moments))
-    params = init_params(cfg, topo, seed=0)
-    opt = init_opt_state(params, cfg, topo, tc)
+    params, opt = init_train_state(cfg, topo, tc)
 
     ckpt = None
     if args.ckpt_dir:

@@ -103,6 +103,16 @@ class ModelConfig:
         """Total model-axis extent (= tp for dense; ep*etp for MoE)."""
         return self.ep * self.etp if self.n_experts else self.tp
 
+    def with_model_parallel(self, mp: int) -> "ModelConfig":
+        """This config with its model axis cut to ``mp`` devices (the
+        mesh's model extent): ``tp = mp`` for dense; for MoE the expert
+        split keeps as much of ``ep`` as divides ``mp`` and gives the rest
+        to per-expert TP."""
+        if self.n_experts:
+            ep = math.gcd(self.ep, mp)
+            return dataclasses.replace(self, tp=mp, ep=ep, etp=mp // ep)
+        return dataclasses.replace(self, tp=mp)
+
     @property
     def n_experts_padded(self) -> int:
         if not self.n_experts:

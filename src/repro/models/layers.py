@@ -40,8 +40,7 @@ def probe_trips(n: int) -> int:
 
 def pvary_like(x, *refs):
     """Promote ``x``'s varying-axes (shard_map vma) to the union of the
-    refs' -- needed for scan carries initialized from constants. No-op on
-    pre-vma jax (compat.HAS_VMA False), where nothing is tracked."""
+    refs' -- needed for scan carries initialized from constants."""
     want = frozenset()
     for r in refs:
         want = want | compat.vma_of(r)
@@ -50,14 +49,10 @@ def pvary_like(x, *refs):
 
 
 def pvary_axes(x, axes):
-    """Mark ``x`` as varying over ``axes`` (no-op outside shard_map/vma)."""
+    """Mark ``x`` as varying over the mesh ``axes`` it does not vary over
+    yet (inside shard_map; an axis not bound there raises)."""
     need = tuple(a for a in axes if a not in compat.vma_of(x))
-    if not need:
-        return x
-    try:
-        return compat.pvary(x, need)
-    except Exception:
-        return x
+    return compat.pvary(x, need) if need else x
 
 
 def rms_norm(x: Array, scale: Array, eps: float = 1e-6) -> Array:

@@ -258,12 +258,13 @@ class Model:
             cnt = cnt + msk.sum()
             return (tot, cnt), None
 
+        # the carry varies over the batch axes only: every term the body
+        # adds is reduced over tp (the max through a tp all_reduce, which is
+        # typed invariant over tp at any group size, the sums by psum)
         zero = layers.pvary_axes(jnp.zeros(()), topo.dp)
-        (tot, cnt), _ = layers.pscan(ce, (zero, zero + 0.0), jnp.arange(nck))
-        tot = compat.replicated_psum(layers.pvary_axes(tot, topo.dp),
-                                     topo.dp)
-        cnt = compat.replicated_psum(layers.pvary_axes(cnt, topo.dp),
-                                     topo.dp)
+        (tot, cnt), _ = layers.pscan(ce, (zero, zero), jnp.arange(nck))
+        tot = compat.replicated_psum(tot, topo.dp)
+        cnt = compat.replicated_psum(cnt, topo.dp)
         loss = tot / jnp.maximum(cnt, 1.0)
         aux = layers.pvary_axes(aux, topo.dp + topo.tp)
         aux_all = compat.replicated_psum(aux, topo.dp + topo.tp) / (

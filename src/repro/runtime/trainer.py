@@ -394,14 +394,14 @@ def make_split_train_step(cfg: ModelConfig, topo: Topology,
     return fwd, fwd_bwd, sync, opt
 
 
-def init_opt_state(params, cfg, topo, tc: TrainConfig):
-    """Optimizer state for :func:`make_train_step`: AdamW moments plus the
-    compressed-hop error-feedback buffers when this run threads them."""
-    state = adamw.init_state(params, tc.adamw)
-    if use_error_feedback(tc, topo.cube):
-        state["ef"] = init_error_feedback(
-            params, param_specs(cfg, topo), topo.cube)
-    return state
+def init_opt_state(cfg, topo, tc: TrainConfig):
+    """Optimizer state for :func:`make_train_step`, zero and placed on the
+    cube: AdamW moments (8-bit scales sized per last-dim shard, see
+    :func:`adamw.state_defs`) plus the compressed-hop error-feedback
+    buffers when this run threads them."""
+    return jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype, device=s.sharding),
+        opt_structs(cfg, topo, tc))
 
 
 def _opt_specs(cfg, topo, tc: TrainConfig):

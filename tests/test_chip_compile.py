@@ -1,0 +1,122 @@
+"""Compiles for a described TPU v5e, with no chip attached: the Pallas
+kernels at real widths, and the qwen3-1.7b decode step at published
+widths with one layer (the train step takes ~20 s to compile, too long to
+keep here). What the chip's compiler refuses (an unaligned tile, a
+primitive with no kernel lowering, a program over the 16 GB of HBM) fails
+here, at no chip time. Nothing runs, so these say nothing about results
+or speed.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import dataclasses
+import os
+
+import pytest
+
+HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile can be written to the persistent cache but
+    # never read back without the chip: keep it out for these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
+
+
+def _structs(sharding, *shapes_dtypes):
+    import jax
+    return [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes_dtypes]
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES, used
+    return used
+
+
+# (B, S, H, KV, hd, causal, window): qwen3-1.7b causal at 2048, the same
+# heads with a sliding window, and a non-causal (encoder) call
+@pytest.mark.parametrize("B,S,H,KV,hd,causal,window", [
+    (1, 2048, 16, 8, 128, True, -1),
+    (1, 2048, 16, 8, 128, True, 512),
+    (2, 1024, 16, 8, 128, False, -1),
+])
+def test_flash_kernel_compiles(one_chip, B, S, H, KV, hd, causal, window):
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.attention.flash import flash_attention
+    q, k, v = _structs(one_chip, ((B, S, H, hd), jnp.bfloat16),
+                       ((B, S, KV, hd), jnp.bfloat16),
+                       ((B, S, KV, hd), jnp.bfloat16))
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window)).lower(q, k, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_rwkv6_kernel_compiles(one_chip, chunk):
+    """rwkv6-7b: d_model 4096 in heads of 64."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.rwkv6.rwkv6 import rwkv6_chunked
+    B, S, H, K = 1, 2048, 64, 64
+    r, k, v, lw = _structs(one_chip, *[((B, S, H, K), jnp.bfloat16)] * 3,
+                           ((B, S, H, K), jnp.float32))
+    (u,) = _structs(one_chip, ((H, K), jnp.float32))
+    compiled = jax.jit(lambda *a: rwkv6_chunked(*a, chunk=chunk)).lower(
+        r, k, v, lw, u).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def _qwen3_one_layer():
+    from repro import configs
+    return dataclasses.replace(configs.get("qwen3-1.7b"), n_layers=1)
+
+
+def test_qwen3_one_layer_decode_step_compiles(v5e):
+    """The serve launcher's decode step over a bf16 cache."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.serve import make_decode_step, serve_topology
+    from repro.models.params import param_structs
+    from repro.models.serving import cache_structs, make_serve_plan
+    cfg = _qwen3_one_layer()
+    topo = serve_topology(cfg, devices=v5e.devices[:1])
+    plan = make_serve_plan(cfg, topo, S_ctx=256, global_batch=4)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=s.sharding),
+        param_structs(cfg, topo))
+    tok = jax.ShapeDtypeStruct((4,), jnp.int32,
+                               sharding=topo.cube.sharding(P()))
+    compiled = make_decode_step(cfg, topo, plan).lower(
+        params, cache_structs(cfg, topo, plan), tok, tok).compile()
+    _fits(compiled)

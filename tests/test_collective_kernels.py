@@ -21,7 +21,7 @@ import pytest
 
 from repro.kernels.collective import (
     RING_ATTN_TOL, all_gather_matmul, matmul_reduce_scatter, ring_attention)
-from repro.models.layers import reference_attention, rms_norm
+from repro.models.layers import reference_attention
 from repro.testing import substrate
 
 
@@ -79,14 +79,16 @@ def test_ring_attention_documented_tolerance(cube_ring8, dtype, H, KV,
 
 # ----------------------------------------------------- matmul comm fusions
 def test_all_gather_matmul_bit_identical(cube_ring8):
-    """ag_prologue with a row-wise block_fn (norm + up-projection) is
-    bitwise equal to gathering first and computing after."""
+    """ag_prologue with a row-wise block_fn (channel scale + up-projection)
+    is bitwise equal to gathering first and computing after, on
+    integer-valued fp32 (exact products and sums, so the backend's choice
+    of matmul accumulation order per block shape cannot show)."""
     comm = cube_ring8.comm("d")
+    x = substrate.integer_payload(cube_ring8, (2, 4, 6), seed=3)
     rng = np.random.RandomState(3)
-    x = rng.randn(8, 2, 4, 6).astype(np.float32)
-    gamma = rng.randn(6).astype(np.float32)
-    wu = rng.randn(6, 5).astype(np.float32)
-    block_fn = lambda b: rms_norm(b, gamma, 1e-6) @ wu
+    gamma = rng.randint(-3, 4, (6,)).astype(np.float32)
+    wu = rng.randint(-3, 4, (6, 5)).astype(np.float32)
+    block_fn = lambda b: (b * gamma) @ wu
 
     fused = _run_ring8(
         cube_ring8,
