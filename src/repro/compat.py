@@ -26,15 +26,11 @@ from __future__ import annotations
 
 import jax
 from jax import lax
+# With check_vma (the default) the varying-axes machinery both checks
+# out_specs replication and lets autodiff insert the gradient psums for
+# replicated leaves.
+from jax import shard_map  # noqa: F401  (re-export)
 from jax.sharding import AxisType
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map``. With ``check_vma`` the varying-axes machinery both
-    checks out_specs replication and lets autodiff insert the gradient psums
-    for replicated leaves."""
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape, axes, *, devices=None):
