@@ -297,7 +297,7 @@ def telemetry_overhead_bench(cfg, topo, step_fn, tc, batch, *,
     inner = _step_timer(step_fn, params, opt_state, batch)
 
     def call():
-        with telemetry.maybe_span("train-step", cat="wall"):
+        with telemetry.maybe_span("train.step", cat="wall"):
             inner()
         telemetry.inc("train.steps")
         telemetry.observe("train.step_seconds", 0.0)

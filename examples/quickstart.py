@@ -353,7 +353,7 @@ print("measured profile steered the recorded program onto the fused ring "
 # 12. unified telemetry (repro.telemetry): one Tracer captures a span
 #     timeline across a train step and the serving engine.  While the
 #     tracer is active it sits on the comm trace stack, so every live
-#     CommEvent becomes a child span under whatever span is open --
+#     CommEvent becomes an instant inside whatever span is open --
 #     carrying flow/stage/est_source/program_id/fused_from provenance --
 #     and lower-cache hits annotate the timeline as instant marks.  The
 #     metrics registry counts what the narrative above only printed, and
@@ -372,27 +372,27 @@ engine.reset_metrics()                   # warmup boundary: fresh registry
 steps_before12 = engine.step_idx         # run() reports cumulative steps
 telemetry.enable_metrics()
 with telemetry.Tracer() as tracer:
-    with tracer.span("train-step", cat="wall"):
+    with tracer.span("train.step", cat="wall"):
         # fresh jit -> retrace -> the step's grad-sync dispatches land as
-        # child spans under the train-step envelope
+        # instants inside the train.step envelope
         jax.block_until_ready(jax.jit(shard_map(
             barrier_grads, mesh=prod.mesh, in_specs=(tspecs, P()),
             out_specs=tspecs, check_vma=False))(tree, b9))
     req12 = Request(rid=9, prompt=[6, 2, 8, 3], max_new=3,
                     arrival=engine.step_idx)
-    serve12 = engine.run([req12])        # serve-step spans + children
+    serve12 = engine.run([req12])        # serve.step spans + children
 telemetry.disable_metrics()
 
 chrome = json.loads(tracer.chrome_trace_json())   # Perfetto-loadable
 evs = chrome["traceEvents"]
-serve_spans = [e for e in evs if e.get("name") == "serve-step"]
+serve_spans = [e for e in evs if e.get("name") == "serve.step"]
 prog_children = [e for e in evs if e.get("cat") == "comm"
                  and e["args"].get("program_id") == "serve-step"]
-assert serve_spans, "each engine decode step opens a serve-step span"
-assert prog_children, "the step program's ops land as comm child spans"
+assert serve_spans, "each engine decode step opens a serve.step span"
+assert prog_children, "the step program's ops land as comm instants"
 assert all("est_source" in e["args"] and "fused_from" in e["args"]
            for e in prog_children)
-assert any(e.get("name") == "lower-cache-hit" for e in evs), \
+assert any(e.get("name") == "program.lower_cache_hit" for e in evs), \
     "warm-cache lowerings annotate the timeline"
 snap = telemetry.REGISTRY.snapshot()
 steps12 = serve12["steps"] - steps_before12
@@ -401,9 +401,9 @@ assert telemetry.REGISTRY.value("program.lower_cache_hits") >= steps12
 assert engine.metrics.value("serve.steps") == steps12
 assert serve12["p50_token_s"] == engine.metrics.quantile(
     "serve.token_seconds", 0.50)
-print(f"telemetry: {len(serve_spans)} serve-step spans, "
-      f"{len(prog_children)} per-op child spans with provenance, "
-      f"{sum(e.get('name') == 'lower-cache-hit' for e in evs)} "
+print(f"telemetry: {len(serve_spans)} serve.step spans, "
+      f"{len(prog_children)} per-op comm instants with provenance, "
+      f"{sum(e.get('name') == 'program.lower_cache_hit' for e in evs)} "
       "lower-cache-hit marks; engine registry is the measurement path")
 
 mon = telemetry.DriftMonitor(min_samples=1)     # judge on first residual
@@ -512,7 +512,8 @@ if os.environ.get("QUICKSTART_SUMMARY"):
                        "serve_step_spans": len(serve_spans),
                        "comm_child_spans": len(prog_children),
                        "lower_cache_hit_marks": sum(
-                           e.get("name") == "lower-cache-hit" for e in evs),
+                           e.get("name") == "program.lower_cache_hit"
+                           for e in evs),
                        "metrics": {k: snap[k] for k in sorted(snap)},
                        "stale": mon.summary()["stale"]}},
                   f, indent=1)

@@ -25,7 +25,7 @@ CommProfile, with ``program_id`` provenance on every CommEvent.
 execute at ``save()`` dispatch — the train step donates its params/opt
 buffers, so the device→host copy must complete before the next step runs —
 while serialization and disk writes run on a bounded background executor
-(``checkpoint:{section}`` spans, ``ckpt.*`` metrics).  Worker failures are
+(``checkpoint.write.{section}`` spans, ``ckpt.*`` metrics).  Worker failures are
 captured and re-raised at ``wait()`` or the next ``save()``, never
 swallowed in the thread.  The manifest is written and the ``.tmp``
 directory renamed only after every section landed, so a killed-mid-write
@@ -158,7 +158,7 @@ class CheckpointManager:
         for name, (lo, hi) in sections.items():
             if hi == lo:
                 continue
-            with _spans.maybe_span(f"checkpoint:gather:{name}", cat="wall",
+            with _spans.maybe_span(f"checkpoint.gather.{name}", cat="wall",
                                    step=step, leaves=hi - lo):
                 if self.topo is not None:
                     host[lo:hi] = reshard.gather_to_host(
@@ -175,7 +175,7 @@ class CheckpointManager:
         self._writing.add(step)
 
         def write_section(name: str, lo: int, hi: int) -> int:
-            with _spans.maybe_span(f"checkpoint:{name}", cat="wall",
+            with _spans.maybe_span(f"checkpoint.write.{name}", cat="wall",
                                    step=step, leaves=hi - lo):
                 nbytes = 0
                 for i in range(lo, hi):
@@ -191,7 +191,7 @@ class CheckpointManager:
                 _telemetry.set_gauge("ckpt.saved_bytes", total)
                 _telemetry.observe("ckpt.save_seconds",
                                    time.monotonic() - t0)
-                _spans.maybe_instant("checkpoint-durable", step=step,
+                _spans.maybe_instant("checkpoint.durable", step=step,
                                      bytes=total)
             finally:
                 self._writing.discard(step)
@@ -447,7 +447,7 @@ class CheckpointManager:
         """Host arrays -> live arrays: one rooted-scatter program per
         section when placement is known, plain ``jnp.asarray`` otherwise."""
         if topo is not None and spec_leaves is not None:
-            with _spans.maybe_span(f"checkpoint:restore:{section}",
+            with _spans.maybe_span(f"checkpoint.restore.{section}",
                                    cat="wall", leaves=len(host)):
                 return reshard.scatter_to_cube(
                     topo, host, spec_leaves,

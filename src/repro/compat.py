@@ -21,6 +21,11 @@ Exports:
   HAS_VMA
       Always True: the supported jax tracks varying axes in avals. Kept
       for the pre-vma gradient-sync path that still branches on it.
+
+Importing it also gives the program's spans
+(``repro.telemetry.spans.maybe_span``) the JAX profiler as a sink: while
+a profile records, each span is a ``jax.profiler.TraceAnnotation`` on the
+device trace's clock.
 """
 from __future__ import annotations
 
@@ -30,7 +35,12 @@ from jax import lax
 # out_specs replication and lets autodiff insert the gradient psums for
 # replicated leaves.
 from jax import shard_map  # noqa: F401  (re-export)
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType
+
+from repro.telemetry import spans as _spans
+
+_spans.install_profiler_sink(TraceAnnotation.is_enabled, TraceAnnotation)
 
 
 def make_mesh(shape, axes, *, devices=None):

@@ -30,7 +30,11 @@ repo is planned, dispatched and observed:
       every dispatch appends a :class:`CommEvent` (primitive, bitmap, chosen
       flow/stage, estimated ICI/DCN bytes and seconds) to any active
       :class:`CommTrace` context.  ``launch/dryrun.py`` and the benchmark
-      harness consume the trace for their ``derived`` columns.
+      harness consume the trace for their ``derived`` columns.  Every
+      dispatch also runs its body under the planner's key
+      (:func:`scope_name`), so each device op of the collective carries,
+      in its HLO ``op_name``, the key that priced it;
+      :func:`scope_estimate` prices a key again.
 
 The legacy :class:`repro.core.collectives.Collectives` class survives as a
 thin deprecated shim delegating here, so the conformance matrix runs
@@ -38,12 +42,14 @@ bit-identically through either surface.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -278,6 +284,32 @@ _FLOW_TO_PLANNER = {
 _FUSED_FLOWS = frozenset(("ring_fused", "ag_prologue", "rs_epilogue"))
 
 
+def scope_name(primitive: str, bitmap: str, flow: str,
+               payload_bytes: int) -> str:
+    """The named scope of one dispatch's device ops:
+    ``comm.<primitive>.<bitmap>.<flow>.<payload bytes per device>``."""
+    return f"comm.{primitive}.{bitmap}.{flow}.{payload_bytes}"
+
+
+@contextlib.contextmanager
+def _scoped(scope: str):
+    """Name the device ops made inside by ``scope``: in their ``op_name``
+    (``jax.named_scope``) and in a ``comm_scope`` frontend attribute.  The
+    attribute is part of the program's persistent-cache key, which leaves
+    names out, so an executable compiled without the scope never stands
+    in for one with it."""
+    with jax.named_scope(scope), set_xla_metadata(comm_scope=scope):
+        yield
+
+
+def scope_estimate(cube: Hypercube, scope: str) -> planner.CommEstimate:
+    """The planner's estimate of the dispatch that ran under ``scope`` (a
+    :func:`scope_name`), priced as the dispatch priced it."""
+    _, primitive, bitmap, flow, payload = scope.split(".")
+    return planner.estimate(cube, primitive, bitmap, int(payload),
+                            algorithm=_FLOW_TO_PLANNER.get(flow, "direct"))
+
+
 def program_mod():
     """Deferred import of :mod:`repro.core.program` (cycle: program records
     through Communicator dispatch)."""
@@ -414,9 +446,10 @@ class Communicator:
                 ici_bytes=est.ici_bytes, dcn_bytes=est.dcn_bytes,
                 seconds=est.seconds, program_id=program_id,
                 fused_from=tuple(fused_from), est_source=est.est_source))
-        return spec.fn(self, x, op=op, **kwargs) \
-            if primitive in ("all_reduce", "reduce_scatter", "reduce") \
-            else spec.fn(self, x, **kwargs)
+        if primitive in ("all_reduce", "reduce_scatter", "reduce"):
+            kwargs["op"] = op
+        with _scoped(scope_name(primitive, self.bitmap, flow, payload)):
+            return spec.fn(self, x, **kwargs)
 
     # ---------------------------------------------------- PE<->PE primitives
     def all_to_all(self, x: Array, *, split_axis: int, concat_axis: int,
@@ -489,8 +522,10 @@ class Communicator:
                 num_instances=self.num_instances, payload_bytes=payload,
                 ici_bytes=est.ici_bytes, dcn_bytes=est.dcn_bytes,
                 seconds=est.seconds, est_source=est.est_source))
-        return compress.compressed_pod_all_reduce(
-            x, self.cube, self.fast_dims, self.slow_dims, block=block)
+        with _scoped(scope_name("all_reduce", self.bitmap, "compressed",
+                                payload)):
+            return compress.compressed_pod_all_reduce(
+                x, self.cube, self.fast_dims, self.slow_dims, block=block)
 
     # ------------------------------------------------- rooted (host) four
     def scatter(self, host_value, *, axis: int | None = None,

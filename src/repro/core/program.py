@@ -437,12 +437,12 @@ class CommProgram:
             if hit is not None:
                 LOWER_STATS["cache_hits"] += 1
                 _telemetry.inc("program.lower_cache_hits")
-                _spans.maybe_instant("lower-cache-hit",
+                _spans.maybe_instant("program.lower_cache_hit",
                                      program_id=self.program_id)
                 return dataclasses.replace(hit, program=self)
         LOWER_STATS["lowered"] += 1
         _telemetry.inc("program.lowered")
-        with _spans.maybe_span(f"lower:{self.program_id}", cat="trace",
+        with _spans.maybe_span("program.lower", cat="trace",
                                program_id=self.program_id,
                                ops=len(self._ops)):
             ops = [dataclasses.replace(o) for o in self._ops]

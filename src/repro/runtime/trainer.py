@@ -527,59 +527,72 @@ class Trainer:
 
     def run(self, params, opt_state, batches, *, start_step=0,
             checkpoint_every=0, log_every=1, log=print):
+        """Train on ``batches``; returns (params, opt_state, history).
+        Spans: ``train.step`` around ``train.dispatch``, ``train.wait``,
+        ``train.fetch`` (metrics to host) and ``train.checkpoint``."""
         step = start_step
         history = []
         for batch in batches:
-            t0 = time.monotonic()
-            with _spans.maybe_span("train-step", cat="wall", step=step):
-                # getattr: tests drive partially-constructed Trainers
-                # (object.__new__) through run()
-                if getattr(self, "split_fns", None) is not None:
-                    params, opt_state, metrics = self._run_split_step(
-                        params, opt_state, batch)
-                elif (_telemetry.enabled()
-                      and not getattr(self, "_sync_priced", True)):
-                    # first metered step: trace the grad-sync events once
-                    # to price the serial/exposed sync-estimate gauges
-                    from repro.core.comm import CommTrace
-                    with CommTrace() as ct:
-                        params, opt_state, metrics = self.step_fn(
-                            params, opt_state, batch)
-                    self._price_sync_estimates(ct.events)
-                    self._sync_priced = True
-                else:
+            with _spans.maybe_span("train.step", step=step):
+                params, opt_state, metrics, dt = self._step(
+                    params, opt_state, batch)
+                step += 1
+                history.append(metrics)
+                if log_every and step % log_every == 0:
+                    log(f"step {step}: loss={metrics['loss']:.4f} "
+                        f"gnorm={metrics['grad_norm']:.3f} {dt*1e3:.0f}ms")
+                if (checkpoint_every and self.checkpointer
+                        and step % checkpoint_every == 0):
+                    # gather-at-dispatch: save() snapshots params/opt to
+                    # host before returning (the jitted step donates both
+                    # buffers), then overlaps serialization + disk writes
+                    # with the next steps
+                    from repro.checkpoint.manager import TrainState
+                    with _spans.maybe_span("train.checkpoint", step=step):
+                        self.checkpointer.save(
+                            step, TrainState(params=params, opt=opt_state))
+        return params, opt_state, history
+
+    def _step(self, params, opt_state, batch):
+        """One step through to its metrics on the host; returns (params,
+        opt_state, metrics, wall seconds)."""
+        t0 = time.monotonic()
+        with _spans.maybe_span("train.dispatch"):
+            # getattr: tests drive partially-constructed Trainers
+            # (object.__new__) through run()
+            if getattr(self, "split_fns", None) is not None:
+                params, opt_state, metrics = self._run_split_step(
+                    params, opt_state, batch)
+            elif (_telemetry.enabled()
+                  and not getattr(self, "_sync_priced", True)):
+                # first metered step: trace the grad-sync events once
+                # to price the serial/exposed sync-estimate gauges
+                from repro.core.comm import CommTrace
+                with CommTrace() as ct:
                     params, opt_state, metrics = self.step_fn(
                         params, opt_state, batch)
-                # block on the step's real outputs before reading the
-                # clock: the param/opt_state updates are not
-                # data-dependent on the logged metrics, so coercing
-                # metrics alone lets async dispatch leak their compute out
-                # of dt -- the straggler deadline and the logged per-step
-                # ms would undercount
-                jax.block_until_ready((params, opt_state))
+                self._price_sync_estimates(ct.events)
+                self._sync_priced = True
+            else:
+                params, opt_state, metrics = self.step_fn(
+                    params, opt_state, batch)
+        # block on the step's real outputs before reading the clock: the
+        # param/opt_state updates are not data-dependent on the logged
+        # metrics, so coercing metrics alone lets async dispatch leak their
+        # compute out of dt -- the straggler deadline and the logged
+        # per-step ms would undercount
+        with _spans.maybe_span("train.wait"):
+            jax.block_until_ready((params, opt_state))
+        with _spans.maybe_span("train.fetch"):
             metrics = {k: float(v) for k, v in metrics.items()}
-            dt = time.monotonic() - t0
-            straggler = bool(self.tc.step_deadline_s
-                             and dt > self.tc.step_deadline_s)
-            if straggler:
-                # straggler mitigation: record and continue -- on a real
-                # cluster this triggers the runtime's slow-host report
-                self.slow_steps += 1
-                metrics["straggler"] = 1.0
-            if _telemetry.enabled():
-                self._record_step_telemetry(dt, straggler)
-            step += 1
-            history.append(metrics)
-            if log_every and step % log_every == 0:
-                log(f"step {step}: loss={metrics['loss']:.4f} "
-                    f"gnorm={metrics['grad_norm']:.3f} {dt*1e3:.0f}ms")
-            if (checkpoint_every and self.checkpointer
-                    and step % checkpoint_every == 0):
-                # gather-at-dispatch: save() snapshots params/opt to host
-                # before returning (the jitted step donates both buffers),
-                # then overlaps serialization + disk writes with the next
-                # steps
-                from repro.checkpoint.manager import TrainState
-                self.checkpointer.save(
-                    step, TrainState(params=params, opt=opt_state))
-        return params, opt_state, history
+        dt = time.monotonic() - t0
+        straggler = bool(self.tc.step_deadline_s
+                         and dt > self.tc.step_deadline_s)
+        if straggler:
+            # straggler mitigation: record and continue -- on a real
+            # cluster this triggers the runtime's slow-host report
+            self.slow_steps += 1
+            metrics["straggler"] = 1.0
+        if _telemetry.enabled():
+            self._record_step_telemetry(dt, straggler)
+        return params, opt_state, metrics, dt

@@ -46,6 +46,7 @@ Admission policies:
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any
 
@@ -63,7 +64,6 @@ from repro.serving import pages as pages_mod
 from repro.serving.pages import (
     PagedServer, PageTable, extract_slot_pages, init_paged_cache,
     inject_slot_pages, make_page_plan, paged_cache_specs)
-from repro.telemetry import drift as _drift
 from repro.telemetry import spans as _spans
 from repro.telemetry.metrics import MetricsRegistry
 
@@ -82,6 +82,7 @@ class Request:
     arrival: int = 0
     # filled by the engine
     out_tokens: list[int] = dataclasses.field(default_factory=list)
+    submitted_at: float = math.nan     # time.perf_counter() at submit
     admitted_step: int = -1
     finished_step: int = -1
     preemptions: int = 0
@@ -232,6 +233,8 @@ class ServeEngine:
                 f"request {req.rid} needs {max(need)} pages on one shard "
                 f"but the pools hold {self.pplan.pages_per_shard} -- it "
                 "could never run even alone")
+        if math.isnan(req.submitted_at):
+            req.submitted_at = time.perf_counter()
         self.queue.append(req)
         self.queue.sort(key=lambda r: r.arrival)
 
@@ -271,8 +274,10 @@ class ServeEngine:
         self.temp_h[slot] = req.temperature
         self._admit_order[slot] = self._stamp = getattr(
             self, "_stamp", 0) + 1
-        if req.admitted_step < 0:
+        if req.admitted_step < 0:              # first admission only
             req.admitted_step = self.step_idx
+            self.metrics.histogram("serve.queue_seconds").observe(
+                time.perf_counter() - req.submitted_at)
         need = np.asarray(self._need(req), np.int64)
         self._slot_commit[slot] = need
         self._committed += need
@@ -353,13 +358,69 @@ class ServeEngine:
 
     def step(self) -> None:
         """One engine step: evict / admit / record-and-run the step program
-        / run the jitted paged-decode + sampling cell."""
-        with _spans.maybe_span("serve-step", cat="wall",
-                               step=self.step_idx):
+        / run the jitted paged-decode + sampling cell.  Spans: ``serve.step``
+        around ``serve.schedule``, ``serve.program``, ``serve.decode``,
+        ``serve.wait`` and ``serve.mirror``."""
+        with _spans.maybe_span("serve.step", step=self.step_idx):
             self._step_inner()
 
     def _step_inner(self) -> None:
         t0 = time.perf_counter()
+        B = self.B
+        with _spans.maybe_span("serve.schedule"):
+            admit, admit_tok, admit_pos, admit_prompts = self._schedule()
+        evict = self._evict_next
+        key = np.array([np.uint32(self.seed), np.uint32(self.step_idx)],
+                       np.uint32)
+
+        # -- ONE recorded CommProgram per decode step: the rooted host->PE
+        #    broadcasts of control state + the PE->host gather of the
+        #    previous step's sampled tokens.  Structure is step-invariant,
+        #    so lowering is a structural-fingerprint cache hit from step 1.
+        with _spans.maybe_span("serve.program"):
+            kvc = self.topo.comm(self.plan.kv_axes)
+            prog = self.topo.cube.program(name="serve-step")
+            with prog:
+                prev = prog.input(jax.ShapeDtypeStruct((B,), jnp.int32))
+                outs = [kvc.broadcast(self.table.array()),
+                        kvc.broadcast(admit), kvc.broadcast(admit_tok),
+                        kvc.broadcast(admit_pos),
+                        kvc.broadcast(admit_prompts),
+                        kvc.broadcast(self.plen_h.copy()),
+                        kvc.broadcast(evict),
+                        kvc.broadcast(self.temp_h.copy()),
+                        kvc.broadcast(key), kvc.gather(prev)]
+                prog.output(*outs)
+            from repro.core.program import LOWER_STATS
+            hits0, low0 = LOWER_STATS["cache_hits"], LOWER_STATS["lowered"]
+            (table_d, admit_d, atok_d, apos_d, aprm_d, plen_d, evict_d,
+             temp_d, key_d, prev_host) = prog.execute(self._sampled)
+            self._lower_hits += LOWER_STATS["cache_hits"] - hits0
+            self._lower_lookups += (LOWER_STATS["cache_hits"] - hits0
+                                    + LOWER_STATS["lowered"] - low0)
+            if self._lower_lookups:
+                self.metrics.gauge("serve.lower_cache_hit_ratio").set(
+                    self._lower_hits / self._lower_lookups)
+            self.programs_recorded += 1
+            self.last_program = prog
+            self._apply_meta(np.asarray(prev_host))
+
+        # -- the fused paged-decode + on-device-sampling step
+        with _spans.maybe_span("serve.decode"):
+            (self._sampled, self._toks, self._pos, self._active,
+             self._prompts, self.pcache) = self._step_fn(
+                self.params, self.pcache, table_d, self._toks, self._pos,
+                self._active, self._prompts, admit_d, atok_d, apos_d,
+                aprm_d, plen_d, evict_d, temp_d, key_d)
+        with _spans.maybe_span("serve.wait"):
+            jax.block_until_ready(self._sampled)
+
+        with _spans.maybe_span("serve.mirror"):
+            self._mirror(t0)
+
+    def _schedule(self):
+        """Evict finished lanes, admit from the queue into free lanes and
+        allocate this step's write blocks; returns the admit arrays."""
         B, pplan = self.B, self.pplan
         self._evict_next = np.zeros(B, bool)
 
@@ -409,60 +470,14 @@ class ServeEngine:
         total_pages = self.pplan.n_shards * self.pplan.pages_per_shard
         self.metrics.gauge("serve.page_occupancy").set(
             1.0 - float(free.sum()) / total_pages if total_pages else 0.0)
+        return admit, admit_tok, admit_pos, admit_prompts
 
-        evict = self._evict_next
-        key = np.array([np.uint32(self.seed), np.uint32(self.step_idx)],
-                       np.uint32)
-
-        # -- ONE recorded CommProgram per decode step: the rooted host->PE
-        #    broadcasts of control state + the PE->host gather of the
-        #    previous step's sampled tokens.  Structure is step-invariant,
-        #    so lowering is a structural-fingerprint cache hit from step 1.
-        kvc = self.topo.comm(self.plan.kv_axes)
-        prog = self.topo.cube.program(name="serve-step")
-        with prog:
-            prev = prog.input(jax.ShapeDtypeStruct((B,), jnp.int32))
-            outs = [kvc.broadcast(self.table.array()),
-                    kvc.broadcast(admit), kvc.broadcast(admit_tok),
-                    kvc.broadcast(admit_pos), kvc.broadcast(admit_prompts),
-                    kvc.broadcast(self.plen_h.copy()),
-                    kvc.broadcast(evict), kvc.broadcast(self.temp_h.copy()),
-                    kvc.broadcast(key), kvc.gather(prev)]
-            prog.output(*outs)
-        from repro.core.program import LOWER_STATS
-        hits0, low0 = LOWER_STATS["cache_hits"], LOWER_STATS["lowered"]
-        te0 = time.perf_counter()
-        with _spans.maybe_span("step-program", cat="wall",
-                               step=self.step_idx,
-                               program_id=prog.program_id):
-            (table_d, admit_d, atok_d, apos_d, aprm_d, plen_d, evict_d,
-             temp_d, key_d, prev_host) = prog.execute(self._sampled)
-        exec_wall = time.perf_counter() - te0
-        self._lower_hits += LOWER_STATS["cache_hits"] - hits0
-        self._lower_lookups += (LOWER_STATS["cache_hits"] - hits0
-                                + LOWER_STATS["lowered"] - low0)
-        if self._lower_lookups:
-            self.metrics.gauge("serve.lower_cache_hit_ratio").set(
-                self._lower_hits / self._lower_lookups)
-        mon = _drift.active_monitor()
-        if mon is not None:
-            mon.observe_plan(prog._lowered_default().plan, exec_wall)
-        self.programs_recorded += 1
-        self.last_program = prog
-        self._apply_meta(np.asarray(prev_host))
-
-        # -- the fused paged-decode + on-device-sampling step
-        (self._sampled, self._toks, self._pos, self._active, self._prompts,
-         self.pcache) = self._step_fn(
-            self.params, self.pcache, table_d, self._toks, self._pos,
-            self._active, self._prompts, admit_d, atok_d, apos_d, aprm_d,
-            plen_d, evict_d, temp_d, key_d)
-        jax.block_until_ready(self._sampled)
-
-        # -- host mirrors advance deterministically; note which lanes just
-        #    produced a *generated* (post-prefill) token
+    def _mirror(self, t0: float) -> None:
+        """Advance the host mirrors deterministically, note which lanes
+        just produced a *generated* (post-prefill) token, and count the
+        step that started at ``t0`` (``time.perf_counter``)."""
         gen_this_step = 0
-        for b in range(B):
+        for b in range(self.B):
             if not self.active_h[b]:
                 continue
             p = int(self.pos_h[b])

@@ -5,7 +5,8 @@ planner / trainer / serving.  See ``docs/TELEMETRY.md`` for the metric
 catalogue and usage recipes.
 
 * :mod:`repro.telemetry.spans` -- nested span timelines with Chrome-trace
-  (Perfetto) and plain-text exports; ingests live CommEvents.
+  (Perfetto) and plain-text exports; ingests live CommEvents; the same
+  spans on a profiler's clock once a profiler sink is installed.
 * :mod:`repro.telemetry.metrics` -- counters / gauges / fixed-bucket
   histograms with JSON-lines and Prometheus text exports; default-off
   module helpers plus per-component registries.
@@ -13,8 +14,7 @@ catalogue and usage recipes.
   (flow, stage, domain) with structured profile-staleness warnings.
 """
 from repro.telemetry.drift import (DEFAULT_BAND, DriftMonitor,
-                                   ProfileStalenessWarning, active_monitor,
-                                   install_monitor)
+                                   ProfileStalenessWarning)
 from repro.telemetry.metrics import (DECLARED, REGISTRY, MetricsRegistry,
                                      active_registry, inc, observe,
                                      scoped_metrics, set_gauge)
@@ -26,9 +26,8 @@ from repro.telemetry.spans import (Tracer, current_tracer, maybe_instant,
 
 __all__ = [
     "DECLARED", "DEFAULT_BAND", "DriftMonitor", "MetricsRegistry",
-    "ProfileStalenessWarning", "REGISTRY", "Tracer", "active_monitor",
-    "active_registry", "current_tracer", "disable_metrics",
-    "enable_metrics", "inc", "install_monitor", "maybe_instant",
-    "maybe_span", "metrics_enabled", "observe", "scoped_metrics",
-    "set_gauge",
+    "ProfileStalenessWarning", "REGISTRY", "Tracer", "active_registry",
+    "current_tracer", "disable_metrics", "enable_metrics", "inc",
+    "maybe_instant", "maybe_span", "metrics_enabled", "observe",
+    "scoped_metrics", "set_gauge",
 ]

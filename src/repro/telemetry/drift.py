@@ -14,10 +14,6 @@ Residual sources:
 * :meth:`DriftMonitor.observe_event` -- a live
   :class:`~repro.core.comm.CommEvent` whose ``seconds`` estimate was
   priced by the installed profile, paired with a measured wall time;
-* :meth:`DriftMonitor.observe_plan` -- a whole
-  :class:`~repro.core.planner.ProgramPlan` against the measured wall time
-  of one execution (the serving engine feeds this each step): the shared
-  ``wall / plan.seconds`` ratio is filed under every op's key;
 * :meth:`DriftMonitor.observe` -- a raw (key, measured, estimated) pair.
 
 By default only ``est_source == "measured"`` estimates are monitored
@@ -31,7 +27,6 @@ from the estimate" instead of re-inventing thresholds.
 from __future__ import annotations
 
 import collections
-import contextlib
 import statistics
 import warnings
 
@@ -123,24 +118,6 @@ class DriftMonitor:
         self.observe(event.flow, event.stage, domain,
                      measured_s, event.seconds)
 
-    def observe_plan(self, plan, measured_s: float) -> None:
-        """A whole ProgramPlan against one measured execution: the shared
-        wall/plan ratio is filed under every op's (flow, stage, domain)."""
-        if self.require_measured and plan.est_source != "measured":
-            return
-        if plan.seconds <= 0.0:
-            return
-        ratio = measured_s / plan.seconds
-        for est in plan.estimates.values():
-            key = (est.algorithm, est.stage, est.dominant())
-            dq = self.residuals.get(key)
-            if dq is None:
-                dq = self.residuals[key] = \
-                    collections.deque(maxlen=self.window)
-            dq.append(ratio)
-            _metrics.inc("drift.observations")
-            self._judge(key, dq)
-
     # ------------------------------------------------------------ judging
     def _judge(self, key: tuple, dq: collections.deque) -> None:
         if key in self.warned or len(dq) < self.min_samples:
@@ -173,26 +150,7 @@ class DriftMonitor:
         }
 
 
-# ------------------------------------------------------ installed monitor
-_MONITORS: list[DriftMonitor] = []
-
-
-def active_monitor() -> DriftMonitor | None:
-    return _MONITORS[-1] if _MONITORS else None
-
-
-@contextlib.contextmanager
-def install_monitor(monitor: DriftMonitor):
-    """Make ``monitor`` the active drift monitor for the scope; live
-    executions (serving engine steps) feed it automatically."""
-    _MONITORS.append(monitor)
-    try:
-        yield monitor
-    finally:
-        _MONITORS.remove(monitor)
-
-
 __all__ = [
     "DEFAULT_BAND", "DriftMonitor", "ProfileStalenessWarning",
-    "active_monitor", "install_monitor", "outside_band", "underrun",
+    "outside_band", "underrun",
 ]

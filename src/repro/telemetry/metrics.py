@@ -122,6 +122,10 @@ DECLARED: dict[str, tuple[str, str]] = {
     "serve.lower_cache_hit_ratio": ("gauge", "Cumulative hit ratio of the "
                                     "per-step program's lower-cache "
                                     "lookups"),
+    "serve.queue_seconds": ("histogram", "Wall seconds from a request's "
+                            "submit() to its first admission into a lane "
+                            "(re-admissions after preemption not "
+                            "counted)"),
     # checkpoint/manager.py -- elastic checkpoint subsystem
     "ckpt.saves": ("counter", "Checkpoint saves dispatched"),
     "ckpt.restores": ("counter", "Checkpoint restores completed "

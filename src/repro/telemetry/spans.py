@@ -1,21 +1,35 @@
-"""Nested span timelines with Chrome-trace and plain-text exports.
+"""Nested span timelines with Chrome-trace and plain-text exports, and
+the same spans on a profiler's clock.
 
-A :class:`Tracer` is a zero-dependency (stdlib-only) span recorder.  While
-active it also registers itself on the comm layer's trace stack, so every
-live :class:`~repro.core.comm.CommEvent` lands as a child span of whatever
-span is currently open -- carrying flow / stage / est_source / program_id /
-fused_from provenance into the timeline.  Spans come in two time domains,
-distinguished by the ``cat`` field rather than separate clocks:
+:func:`maybe_span` is the program's one span API.  Each span goes to two
+sinks, each only while it listens:
+
+* the innermost active :class:`Tracer` -- a zero-dependency (stdlib-only)
+  recorder on an injectable monotonic clock (default
+  ``time.perf_counter``; tests inject a fake clock so exports are
+  byte-deterministic);
+* a profiler, once :func:`install_profiler_sink` gave one (``repro.compat``
+  installs ``jax.profiler.TraceAnnotation`` on import), while it records.
+  The spans then sit on the device trace's clock, beside the device's ops.
+
+With no active Tracer and no recording profiler a span costs one list
+check and one call of the profiler's enabled probe.  Span names are
+dotted, layer first (``serve.step``, ``train.wait``, ``checkpoint.gather``);
+arguments (``step=``) reach the profiler only while it records.  Installing
+the profiler sink also hooks the garbage collector: each collection is a
+``host.gc`` span on the profiler's clock.
+
+While a Tracer is active it also registers itself on the comm layer's trace
+stack, so every live :class:`~repro.core.comm.CommEvent` lands as an
+instant carrying flow / stage / est_source / program_id / fused_from
+provenance and the planner's ``est_seconds``.  An estimate is not a
+duration, so it is never drawn as one on the timeline of measured spans.
+Spans come in two time domains, distinguished by the ``cat`` field rather
+than separate clocks:
 
 * ``trace`` -- host-side work that happens at trace/lower/plan time
   (program recording, lowering passes, joint planning);
 * ``wall``  -- wall-clock phases (dispatch, train/serve step loops).
-
-Both are stamped with the same injectable monotonic clock (default
-``time.perf_counter``); tests inject a fake clock so exports are
-byte-deterministic.  CommEvent child spans get their *duration* from the
-event's planner estimate (``event.seconds``) -- the timeline shows where
-time is *expected* to go inside a step whose envelope is measured.
 
 Exports:
 
@@ -27,6 +41,7 @@ Exports:
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -34,26 +49,95 @@ import time
 _ACTIVE: list["Tracer"] = []
 
 
+def _never() -> bool:
+    return False
+
+
+# the profiler sink: ``_recording()`` says whether a profile records,
+# ``_annotation(name, **args)`` opens a span on its clock
+_recording = _never
+_annotation = None
+
+
+def install_profiler_sink(recording, annotation) -> None:
+    """Send every span to a profiler as well, while ``recording()`` is
+    true; ``annotation(name, **args)`` is a context manager that opens one
+    span on the profiler's clock.  Also hooks ``host.gc`` spans onto the
+    garbage collector.  Installing again replaces the sink."""
+    global _recording, _annotation
+    _recording, _annotation = recording, annotation
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
+
+
+_GC_OPEN: list = []
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``host.gc`` span per collection while a
+    profile records (collections never overlap, so one slot suffices)."""
+    if phase == "start":
+        if _recording():
+            ann = _annotation("host.gc", generation=info["generation"])
+            ann.__enter__()
+            _GC_OPEN.append(ann)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
 def current_tracer() -> "Tracer | None":
     """The innermost active tracer, or None."""
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-@contextlib.contextmanager
-def maybe_span(name: str, cat: str = "wall", **args):
-    """Open a span on the active tracer if there is one; no-op otherwise.
+class _Off:
+    """The span when nothing listens: enters and exits doing nothing."""
+    __slots__ = ()
 
-    The disabled path is one list check -- cheap enough for hot loops.
-    """
-    if not _ACTIVE:
-        yield None
-        return
-    tr = _ACTIVE[-1]
-    handle = tr.begin(name, cat=cat, **args)
-    try:
-        yield handle
-    finally:
-        tr.end(handle)
+    def __enter__(self):
+        return None
+
+    def __exit__(self, exc_type, exc, tb):
+        return None
+
+
+_OFF = _Off()
+
+
+class _On:
+    """A span for the listening sinks: the active Tracer's (its handle is
+    what ``with`` yields) and the profiler's."""
+    __slots__ = ("name", "cat", "args", "tracer", "handle", "ann")
+
+    def __init__(self, name, cat, args):
+        self.name, self.cat, self.args = name, cat, args
+        self.tracer = _ACTIVE[-1] if _ACTIVE else None
+        self.handle = None
+        self.ann = _annotation(name, **args) if _recording() else None
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        if self.tracer is not None:
+            self.handle = self.tracer.begin(self.name, cat=self.cat,
+                                            **self.args)
+        return self.handle
+
+    def __exit__(self, exc_type, exc, tb):
+        if self.tracer is not None:
+            self.tracer.end(self.handle)
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
+        return None
+
+
+def maybe_span(name: str, cat: str = "wall", **args):
+    """A span on the active tracer and on a recording profiler, each if
+    there is one; a no-op otherwise.  ``with`` yields the tracer's handle
+    (None without a tracer)."""
+    if _ACTIVE or _recording():
+        return _On(name, cat, args)
+    return _OFF
 
 
 def maybe_instant(name: str, **args) -> None:
@@ -177,8 +261,8 @@ class Tracer:
                                   tid=self._tid_here()))
 
     def record(self, event) -> None:
-        """CommTrace duck-type hook: ingest a live CommEvent as a child
-        span whose duration is the event's planner estimate."""
+        """CommTrace duck-type hook: ingest a live CommEvent as an instant
+        carrying its provenance and the planner's estimate."""
         self.comm_events.append(event)
         args = {
             "primitive": event.primitive,
@@ -195,9 +279,8 @@ class Tracer:
             "est_seconds": event.seconds,
         }
         self._events.append(_Span(
-            f"comm:{event.primitive}", "comm", args, self._now_us(),
-            len(self._stack) + 1, dur=round(event.seconds * 1e6, 3),
-            tid=self._tid_here()))
+            f"comm.{event.primitive}", "comm", args, self._now_us(),
+            len(self._stack), ph="i", tid=self._tid_here()))
 
     # -------------------------------------------------------------- exports
     def finished(self) -> list:
@@ -237,4 +320,5 @@ class Tracer:
         return "\n".join(lines) + ("\n" if lines else "")
 
 
-__all__ = ["Tracer", "current_tracer", "maybe_instant", "maybe_span"]
+__all__ = ["Tracer", "current_tracer", "install_profiler_sink",
+           "maybe_instant", "maybe_span"]

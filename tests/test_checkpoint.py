@@ -142,7 +142,7 @@ def test_async_write_error_surfaces_at_next_save(tmp_path, monkeypatch):
 
 def test_async_save_overlaps_and_spans_cross_threads(tmp_path, monkeypatch):
     """save() returns after the host gather; the writes land on the
-    executor.  Proven via spans: the worker's ``checkpoint:params`` span
+    executor.  Proven via spans: the worker's ``checkpoint.write.params`` span
     lives on its own tracer lane and extends past the save() dispatch."""
     state = _tiny_state()
     orig_save = np.save
@@ -167,10 +167,10 @@ def test_async_save_overlaps_and_spans_cross_threads(tmp_path, monkeypatch):
     assert dispatch < slowest <= durable
 
     spans = {sp.name: sp for sp in tr.finished()}
-    main_tid = spans["checkpoint:gather:params"].tid
-    assert spans["checkpoint:params"].tid != main_tid  # worker lane
-    assert spans["checkpoint:opt"].tid != main_tid
-    assert any(sp.name == "checkpoint-durable" and sp.ph == "i"
+    main_tid = spans["checkpoint.gather.params"].tid
+    assert spans["checkpoint.write.params"].tid != main_tid  # worker lane
+    assert spans["checkpoint.write.opt"].tid != main_tid
+    assert any(sp.name == "checkpoint.durable" and sp.ph == "i"
                for sp in tr.finished())
     assert mgr.all_steps() == [1]
 
@@ -210,8 +210,8 @@ def test_trainer_step_does_not_block_on_write(tmp_path, monkeypatch):
                     log_every=0, log=lambda *_: None)
         mgr.wait()
 
-    steps = [sp for sp in tr.finished() if sp.name == "train-step"]
-    durable = [sp for sp in tr.finished() if sp.name == "checkpoint-durable"]
+    steps = [sp for sp in tr.finished() if sp.name == "train.step"]
+    durable = [sp for sp in tr.finished() if sp.name == "checkpoint.durable"]
     assert len(steps) == 3 and durable
     # the write takes at least n_leaves * delay; the step that ran behind
     # it finished long before the durable instant

@@ -8,13 +8,18 @@ through program lowering, the trainer and the serving engine.
 * every registered metric name appears in the docs table (meta-test);
 * the disabled path writes nothing (default-off contract);
 * the drift monitor warns exactly once per stale (flow, stage, domain)
-  with the retune recipe, stays quiet in-band, and is fed by live engine
-  steps; dryrun's byte-underrun check shares its band;
+  with the retune recipe and stays quiet in-band; dryrun's byte-underrun
+  check shares its band;
 * the serving engine's registry is the single measurement path run()
-  reports from; trainer telemetry fills step/phase histograms.
+  reports from; trainer telemetry fills step/phase histograms;
+* spans reach the profiler only while it records: a real CPU profile of
+  engine steps, a train step and a garbage collection holds the catalogue's
+  spans, nested; each collective's device ops carry the planner's key.
 """
 import dataclasses
+import gc
 import json
+import time
 import warnings
 from pathlib import Path
 
@@ -25,6 +30,7 @@ import pytest
 from repro import telemetry
 from repro.core.comm import CommEvent
 from repro.telemetry import drift as drift_mod
+from repro.telemetry import spans
 from repro.telemetry.metrics import DECLARED
 from repro.testing import substrate
 
@@ -83,7 +89,7 @@ def test_chrome_trace_deterministic_for_fixed_program(cube_ring8):
     assert any(e["args"].get("program_id") == "fixed" for e in comm_evs)
     # plain-text timeline carries the same spans for CI logs
     text = tracers[0].timeline()
-    assert "step [wall]" in text and "comm:" in text
+    assert "step [wall]" in text and "@ comm.all_reduce" in text
 
 
 def test_chrome_trace_roundtrip(cube_ring8):
@@ -283,27 +289,47 @@ def test_engine_serve_trace_chrome_deterministic():
         blobs.append(tr.chrome_trace_json())
     assert blobs[0] == blobs[1]
     evs = json.loads(blobs[0])["traceEvents"]
-    steps = [e for e in evs if e["name"] == "serve-step"]
-    assert steps, "each engine step must open a serve-step span"
+    steps = [e for e in evs if e["name"] == "serve.step"]
+    assert steps, "each engine step must open a serve.step span"
     comm = [e for e in evs if e["cat"] == "comm"]
     assert comm and all("est_source" in e["args"] for e in comm)
     assert any(e["args"].get("program_id") == "serve-step" for e in comm)
     # lower-cache hits annotate the timeline from step 2 on
-    hits = [e for e in evs if e["name"] == "lower-cache-hit"]
+    hits = [e for e in evs if e["name"] == "program.lower_cache_hit"]
     assert hits and all(e["ph"] == "i" for e in hits)
 
 
-def test_engine_feeds_installed_drift_monitor():
-    # tp=2: group-size-1 plans estimate zero seconds and are (correctly)
-    # skipped, so the drift path needs a real tensor-parallel step program
-    cfg, eng = _setup_engine(2, tp=2)
-    mon = telemetry.DriftMonitor(band=(1e-12, 1e12), min_samples=1,
-                                 require_measured=False)
-    with telemetry.install_monitor(mon):
-        m = eng.run(_serve_trace(cfg, 2))
-    assert mon.residuals, "live steps must feed wall/plan residuals"
-    assert sum(len(dq) for dq in mon.residuals.values()) >= m["steps"]
-    assert mon.stale() == []             # band is deliberately huge
+def test_queue_seconds_counts_each_request_once():
+    # tight pools under lazy admission preempt: a re-admitted request has
+    # waited once already and is not counted again
+    cfg, eng = _setup_engine(3, tp=2, pages_per_shard=4, admission="lazy")
+    from repro.serving import poisson_trace
+    reqs = poisson_trace(6, rate=1.0, plen_range=(3, 8),
+                         max_new_range=(3, 6), vocab=cfg.vocab_size, seed=3)
+    m = eng.run(reqs)
+    reg = eng.metrics
+    assert m["preemptions"] > 0, "pools sized to force preemption"
+    assert reg.value("serve.admitted") == 6 + m["preemptions"]
+    h = reg.histogram("serve.queue_seconds")
+    assert h.count == len(m["finished"]) == 6
+    assert min(h.samples) >= 0.0
+
+
+def test_queue_seconds_runs_from_submit_to_admission():
+    cfg, eng = _setup_engine(1)
+    from repro.serving import Request
+    first = Request(rid=0, prompt=[1, 2], max_new=2)
+    second = Request(rid=1, prompt=[3, 4], max_new=2)
+    eng.submit(first)
+    eng.submit(second)          # one lane: waits for the first to finish
+    t_submit = time.perf_counter()
+    while eng.queue or eng.active_h.any():
+        eng.step()
+        if second.admitted_step < 0:
+            t_before = time.perf_counter()
+    waits = sorted(eng.metrics.histogram("serve.queue_seconds").samples)
+    assert len(waits) == 2
+    assert waits[1] >= t_before - t_submit > waits[0]
 
 
 # ---------------------------------------------------------------- trainer
@@ -347,7 +373,7 @@ def test_trainer_step_metrics_and_span():
     assert telemetry.REGISTRY.value("train.steps") == 2
     assert telemetry.REGISTRY.get("train.step_seconds").count == 2
     evs = json.loads(tracer.chrome_trace_json())["traceEvents"]
-    assert sum(e["name"] == "train-step" for e in evs) == 2
+    assert sum(e["name"] == "train.step" for e in evs) == 2
     assert np.isfinite(hist[-1]["loss"])
 
 
@@ -376,3 +402,166 @@ def test_split_step_rejects_compressed_path():
     tc = dataclasses.replace(tc, compress_pod_grads=True)
     with pytest.raises(ValueError, match="plain gradient-sync"):
         make_split_train_step(cfg, topo, tc)
+
+
+# ------------------------------------------------------ profiler sink
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter/exit."""
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.args))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def _program_entries(log):
+    # a collection may start at any allocation; its host.gc span is not
+    # this test's
+    return [e for e in log if e[1] != "host.gc"]
+
+
+def test_maybe_span_calls_profiler_sink_only_while_recording(monkeypatch):
+    recording = [False]
+    monkeypatch.setattr(spans, "_recording", lambda: recording[0])
+    monkeypatch.setattr(spans, "_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    with spans.maybe_span("serve.step", step=3) as h:
+        assert h is None
+    assert _program_entries(_FakeAnnotation.log) == []
+    # the off path hands out one shared no-op
+    assert spans.maybe_span("a", step=1) is spans.maybe_span("b")
+
+    recording[0] = True
+    with spans.maybe_span("serve.step", step=3) as h:
+        with spans.maybe_span("serve.wait"):
+            pass
+    assert h is None                   # no tracer: nothing to hand back
+    assert _program_entries(_FakeAnnotation.log) == [
+        ("enter", "serve.step", {"step": 3}), ("enter", "serve.wait", {}),
+        ("exit", "serve.wait"), ("exit", "serve.step")]
+
+    # both sinks at once: the tracer keeps its handle and category
+    _FakeAnnotation.log.clear()
+    with telemetry.Tracer(clock=FakeClock()) as tr:
+        with spans.maybe_span("program.lower", cat="trace", ops=2) as h:
+            pass
+    assert h.name == "program.lower" and h.cat == "trace"
+    assert [sp.name for sp in tr.finished()] == ["program.lower"]
+    assert _program_entries(_FakeAnnotation.log) == [
+        ("enter", "program.lower", {"ops": 2}), ("exit", "program.lower")]
+
+    recording[0] = False
+    _FakeAnnotation.log.clear()
+    with telemetry.Tracer(clock=FakeClock()) as tr:
+        with spans.maybe_span("train.step", step=0):
+            pass
+    assert _program_entries(_FakeAnnotation.log) == []
+    assert [sp.name for sp in tr.finished()] == ["train.step"]
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a real jax.profiler trace; returns the host
+    planes' events as (name, start ns, end ns, stats)."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for p in ProfileData.from_file(str(pb)).planes
+            if p.name.startswith("/host") for ln in p.lines
+            for e in ln.events]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+SERVE_PHASES = ("serve.schedule", "serve.program", "serve.decode",
+                "serve.wait", "serve.mirror")
+
+
+def test_engine_spans_on_the_profiler_trace(tmp_path):
+    cfg, eng = _setup_engine(2)
+    eng.run(_serve_trace(cfg, 2))        # compiles outside the profile
+    from repro.serving import Request
+    eng.submit(Request(rid=7, prompt=[1, 2, 3], max_new=4,
+                       arrival=eng.step_idx))
+    first = eng.step_idx
+    evs = _profiled(tmp_path, lambda: (eng.step(), eng.step()))
+    steps = sorted(e for e in evs if e[0] == "serve.step")
+    assert [e[3]["step"] for e in steps] == [first, first + 1]
+    for step in steps:
+        kids = sorted((e for e in evs if e[0] in SERVE_PHASES
+                       and _inside(e, step)), key=lambda e: e[1])
+        assert [k[0] for k in kids] == list(SERVE_PHASES)
+        # one after another, none overlapping
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    assert sum(e[0] in SERVE_PHASES for e in evs) == 2 * len(SERVE_PHASES)
+
+
+def test_trainer_spans_on_the_profiler_trace(tmp_path):
+    from repro.runtime.trainer import Trainer
+    cfg, topo, tc, params, opt = _setup_train()
+    tr = Trainer(cfg, topo, tc)
+    batches = list(_batches(cfg, 2))
+    params, opt, _ = tr.run(params, opt, batches[:1], log_every=0,
+                            log=lambda *_: None)       # compile
+    evs = _profiled(tmp_path, lambda: tr.run(
+        params, opt, batches[1:], start_step=1, log_every=0,
+        log=lambda *_: None))
+    (step,) = [e for e in evs if e[0] == "train.step"]
+    assert step[3] == {"step": 1}
+    kids = sorted((e for e in evs if e[0].startswith("train.")
+                   and e is not step), key=lambda e: e[1])
+    assert [k[0] for k in kids] == ["train.dispatch", "train.wait",
+                                    "train.fetch"]
+    assert all(_inside(k, step) for k in kids)
+
+
+def test_gc_is_a_span_on_the_profiler_trace(tmp_path):
+    evs = _profiled(tmp_path, lambda: gc.collect())
+    gcs = [e for e in evs if e[0] == "host.gc"]
+    assert gcs and any(e[3].get("generation") == 2 for e in gcs)
+    assert all(e[2] > e[1] for e in gcs)
+
+
+def test_dispatch_scope_carries_the_planner_key(cube_2x4):
+    from repro.compat import shard_map
+    from repro.core.comm import CommTrace, scope_estimate, scope_name
+    x = substrate.integer_payload(cube_2x4, (4, 16), seed=1)
+    comm = cube_2x4.comm("01")
+    spec = substrate.global_spec(cube_2x4, 2)
+    f = jax.jit(shard_map(lambda v: comm.all_reduce(v), mesh=cube_2x4.mesh,
+                          in_specs=spec, out_specs=spec, check_vma=False))
+    with CommTrace() as ct:
+        hlo = f.lower(x).compile().as_text()
+    (ev,) = ct.events
+    key = scope_name("all_reduce", "01", ev.flow, ev.payload_bytes)
+    assert key == f"comm.all_reduce.01.{ev.flow}.{4 * 16 * 4}"
+    (line,) = [ln for ln in hlo.splitlines() if "all-reduce(" in ln]
+    assert f'op_name="jit(<lambda>)/shard_map/{key}/' in line
+    assert f'comm_scope="{key}"' in line
+    assert scope_estimate(cube_2x4, key).seconds == ev.seconds
+
+
+def test_tracer_keeps_comm_events_as_instants(cube_ring8):
+    prog = _fixed_program(cube_ring8)
+    prog._lowered_default()
+    x = substrate.integer_payload(cube_ring8, (2, 16), seed=5)
+    with telemetry.Tracer(clock=FakeClock()) as tr:
+        with tr.span("step", cat="wall"):
+            substrate.run_per_shard(cube_ring8, lambda v: prog.execute(v), x)
+    evs = json.loads(tr.chrome_trace_json())["traceEvents"]
+    comm = [e for e in evs if e["cat"] == "comm"]
+    assert comm and all(e["ph"] == "i" and "dur" not in e for e in comm)
+    assert all(e["name"] == f"comm.{e['args']['primitive']}" for e in comm)
+    assert all(e["args"]["est_seconds"] == ev.seconds
+               for e, ev in zip(comm, tr.comm_events))
