@@ -216,11 +216,8 @@ def attn_decode(cfg: ModelConfig, topo: Topology, w: dict, x: Array,
         n_shards = topo.size(kv_axes)
         S_loc = cache_k.shape[1]
         S_cache = S_loc * n_shards
-        my_lo = lax.axis_index(kv_axes) * S_loc
-        slot = (pos % S_cache) if rolling else pos             # (B,)
-        loc = slot - my_lo
-        in_rng = (loc >= 0) & (loc < S_loc)
-        idx = jnp.clip(loc, 0, S_loc - 1)
+        my_lo, idx, in_rng = decode_slot(pos, S_loc, n_shards, kv_axes,
+                                         rolling)
         bidx = jnp.arange(B)
         if int8_cache:
             ks = jnp.maximum(jnp.abs(k_new).max(-1), 1e-6) / 127.0
@@ -288,6 +285,21 @@ def attn_decode(cfg: ModelConfig, topo: Topology, w: dict, x: Array,
     c = dict(c)
     c[kk], c[vk] = cache_k, cache_v
     return x + out.astype(x.dtype), c
+
+
+def decode_slot(pos: Array, S_loc: int, n_shards: int, kv_axes,
+                rolling: bool):
+    """Where decode writes each lane's new K/V row in this shard's chunk of
+    ``S_loc`` slots (of ``S_loc * n_shards``, sequence-sharded over
+    ``kv_axes``). Returns (the chunk's first slot, the lane's slot within
+    the chunk clipped into it (B,), whether the slot is in the chunk (B,))."""
+    S_cache = S_loc * n_shards
+    my_lo = lax.axis_index(kv_axes) * S_loc
+    slot = (pos % S_cache) if rolling else pos             # (B,)
+    loc = slot - my_lo
+    in_rng = (loc >= 0) & (loc < S_loc)
+    idx = jnp.clip(loc, 0, S_loc - 1)
+    return my_lo, idx, in_rng
 
 
 def _rope_decode(q, pos, theta):

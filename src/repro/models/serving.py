@@ -145,15 +145,32 @@ def init_cache(cfg, topo, plan):
 
 
 # ------------------------------------------------------------------ decode
+def _layer_in(key, cin):
+    return dict(cin)
+
+
+def _layer_out(key, c):
+    return c
+
+
 class Server:
     def __init__(self, cfg: ModelConfig, topo: Topology, plan: ServePlan,
                  resident: bool = False):
         self.cfg, self.topo, self.plan = cfg, topo, plan
         self.model = Model(cfg, topo, resident=resident)
 
-    def decode_shard(self, params, cache, tokens: Array, pos: Array):
+    def decode_shard(self, params, cache, tokens: Array, pos: Array, *,
+                     layer_cache=_layer_in, layer_out=_layer_out):
         """One decode step. tokens, pos: (B_l,) int32. Returns
-        (logits (B_l, V_local), new cache)."""
+        (logits (B_l, V_local), what the layer scan stacked).
+
+        The scan slices ``cache`` per unit. ``layer_cache(key, cin)`` turns
+        mixer ``key``'s slice into the cache dict the layer decodes on, and
+        ``layer_out(key, c)`` picks what the scan stacks from the updated
+        dict. Both default to the identity: the contiguous cache in, the
+        new cache out. :class:`repro.serving.pages.PagedServer` passes
+        hooks that read a layer's view from the page pools and emit only
+        the row each lane wrote."""
         cfg, topo, plan = self.cfg, self.topo, self.plan
         m = self.model
         emb_l = m._gather_embed(params)
@@ -170,7 +187,7 @@ class Server:
                 if window is None:
                     window = xs["windows"][key]
                 mixer = m.mixers[p]
-                c = dict(cin[key])
+                c = layer_cache(key, cin[key])
                 if mixer == ATTN:
                     rolling = plan.S_cache < plan.S_ctx
                     x, c = blocks.attn_decode(
@@ -198,7 +215,7 @@ class Server:
                     x, shift = blocks.rwkv_channel_mix_decode(
                         cfg, topo, w, x, c["cm_shift"])
                     c["cm_shift"] = shift.astype(c["cm_shift"].dtype)
-                cout[key] = c
+                cout[key] = layer_out(key, c)
             return x, cout
 
         xs = dict(params["units"])

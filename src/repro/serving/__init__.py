@@ -8,11 +8,13 @@ from repro.serving.engine import Request, ServeEngine, poisson_trace
 from repro.serving.pages import (
     PAGED_KEYS, PagePlan, PagedServer, PageTable, extract_slot_pages,
     gather_view, init_paged_cache, inject_slot_pages, local_block_ids,
-    make_page_plan, paged_cache_defs, paged_cache_specs, scatter_view)
+    make_page_plan, paged_cache_defs, paged_cache_specs, row_targets,
+    write_rows)
 
 __all__ = [
     "PAGED_KEYS", "PagePlan", "PageTable", "PagedServer", "Request",
     "ServeEngine", "extract_slot_pages", "gather_view", "init_paged_cache",
     "inject_slot_pages", "local_block_ids", "make_page_plan",
-    "paged_cache_defs", "paged_cache_specs", "poisson_trace", "scatter_view",
+    "paged_cache_defs", "paged_cache_specs", "poisson_trace", "row_targets",
+    "write_rows",
 ]

@@ -11,10 +11,12 @@ One engine step serves every in-flight request at once and costs exactly:
     first (``LOWER_STATS["cache_hits"]`` grows by one per step) -- per-token
     collectives are planned once and overlap-scheduled under any installed
     profile;
-  * **one jitted shard_map step** wrapping the paged flash-decode cell
-    (:class:`repro.serving.pages.PagedServer` around the unchanged
-    ``Server.decode_shard``) plus device-side sampling, so no logits ever
-    cross to the host.
+  * **one jitted shard_map step** (:func:`make_step`) wrapping the paged
+    flash-decode cell (:class:`repro.serving.pages.PagedServer`: the
+    unchanged ``Server.decode_shard`` arithmetic on each layer's view,
+    gathered from the page pools inside the layer scan, with one row per
+    lane written back after it) plus device-side sampling, so no logits
+    ever cross to the host.
 
 Scheduling is continuous batching with slot reuse: requests admit from the
 arrival queue into free batch lanes, prefill runs *through the decode cell*
@@ -62,7 +64,7 @@ from repro.models.serving import ServePlan, Server
 from repro.models.topology import Topology
 from repro.serving import pages as pages_mod
 from repro.serving.pages import (
-    PagedServer, PageTable, extract_slot_pages, init_paged_cache,
+    PagedServer, PagePlan, PageTable, extract_slot_pages, init_paged_cache,
     inject_slot_pages, make_page_plan, paged_cache_specs)
 from repro.telemetry import spans as _spans
 from repro.telemetry.metrics import MetricsRegistry
@@ -97,6 +99,64 @@ class Request:
         return self.plen + self.max_new - 1
 
 
+def make_step(cfg: ModelConfig, topo: Topology, plan: ServePlan,
+              pplan: PagePlan):
+    """The engine's jitted step over the page pools (donated): paged decode
+    of every lane, on-device sampling, and the lanes' advance.  Returns
+    (sampled, toks, pos, active, prompts, new pools)."""
+    paged = PagedServer(Server(cfg, topo, plan), pplan)
+    P_max = plan.S_ctx
+    vocab = cfg.vocab_size
+
+    def step_shard(params, pcache, table, toks, pos, active, prompts,
+                   admit, admit_tok, admit_pos, admit_prompts, plen,
+                   evict, temps, key):
+        tpc = topo.comm(topo.tp)
+        # merge this step's schedule into the carried lane state
+        active = (active & ~evict) | admit
+        toks = jnp.where(admit, admit_tok, toks)
+        pos = jnp.where(admit, admit_pos, pos)
+        prompts = jnp.where(admit[:, None], admit_prompts, prompts)
+
+        logits, pcache = paged.decode_shard(params, pcache, table,
+                                            toks, pos)
+        # ---- on-device sampling over the vocab-sharded logits
+        V_loc = logits.shape[-1]
+        me = compat.axis_index(topo.tp)
+        gid = me * V_loc + jnp.arange(V_loc, dtype=jnp.int32)
+        neg = jnp.finfo(jnp.float32).min
+        logits = jnp.where(gid[None, :] < vocab, logits, neg)
+        k = jax.random.fold_in(key, me)
+        g = jax.random.gumbel(k, logits.shape, jnp.float32)
+        warm = logits / jnp.maximum(temps, 1e-6)[:, None] + g
+        eff = jnp.where(temps[:, None] > 0.0, warm, logits)
+        # collective argmax: max over shards, then min global id
+        # among the (bitwise-equal on the owner) maximizers
+        m_loc = eff.max(axis=-1)
+        m_all = tpc.all_reduce(m_loc, op="max")
+        cand = jnp.where(eff == m_all[:, None], gid[None, :],
+                         jnp.int32(_I32MAX)).min(axis=-1)
+        sampled = tpc.all_reduce(cand, op="min")
+        # ---- teacher-force prefill, advance the lanes
+        nxt_p = jnp.take_along_axis(
+            prompts, jnp.clip(pos + 1, 0, P_max - 1)[:, None],
+            axis=1)[:, 0]
+        nxt = jnp.where(pos + 1 < plen, nxt_p, sampled)
+        toks = jnp.where(active, nxt, toks)
+        pos = jnp.where(active, pos + 1, pos)
+        return sampled, toks, pos, active, prompts, pcache
+
+    pspec = param_specs(cfg, topo)
+    cspec = paged_cache_specs(cfg, topo, plan, pplan)
+    rep = P()
+    fn = compat.shard_map(
+        step_shard, mesh=topo.cube.mesh,
+        in_specs=(pspec, cspec) + (rep,) * 13,
+        out_specs=(rep, rep, rep, rep, rep, cspec),
+        check_vma=False)
+    return jax.jit(fn, donate_argnums=(1,))
+
+
 class ServeEngine:
     """Continuous-batching decode server on the serve topology."""
 
@@ -125,7 +185,6 @@ class ServeEngine:
 
         self.table = PageTable(self.pplan, self.B)
         self.pcache = init_paged_cache(cfg, topo, plan, self.pplan)
-        self.paged = PagedServer(Server(cfg, topo, plan), self.pplan)
 
         # host mirrors (deterministic: no token values needed)
         self.slot_req: list[Request | None] = [None] * self.B
@@ -161,61 +220,7 @@ class ServeEngine:
         self._lower_hits = 0
         self._lower_lookups = 0
 
-        self._step_fn = self._build_step()
-
-    # ----------------------------------------------------------- jitted step
-    def _build_step(self):
-        topo, plan, cfg = self.topo, self.plan, self.cfg
-        pplan, paged, P_max = self.pplan, self.paged, self.P_max
-        vocab = cfg.vocab_size
-
-        def step_shard(params, pcache, table, toks, pos, active, prompts,
-                       admit, admit_tok, admit_pos, admit_prompts, plen,
-                       evict, temps, key):
-            tpc = topo.comm(topo.tp)
-            # merge this step's schedule into the carried lane state
-            active = (active & ~evict) | admit
-            toks = jnp.where(admit, admit_tok, toks)
-            pos = jnp.where(admit, admit_pos, pos)
-            prompts = jnp.where(admit[:, None], admit_prompts, prompts)
-
-            logits, pcache = paged.decode_shard(params, pcache, table,
-                                                toks, pos)
-            # ---- on-device sampling over the vocab-sharded logits
-            V_loc = logits.shape[-1]
-            me = compat.axis_index(topo.tp)
-            gid = me * V_loc + jnp.arange(V_loc, dtype=jnp.int32)
-            neg = jnp.finfo(jnp.float32).min
-            logits = jnp.where(gid[None, :] < vocab, logits, neg)
-            k = jax.random.fold_in(key, me)
-            g = jax.random.gumbel(k, logits.shape, jnp.float32)
-            warm = logits / jnp.maximum(temps, 1e-6)[:, None] + g
-            eff = jnp.where(temps[:, None] > 0.0, warm, logits)
-            # collective argmax: max over shards, then min global id
-            # among the (bitwise-equal on the owner) maximizers
-            m_loc = eff.max(axis=-1)
-            m_all = tpc.all_reduce(m_loc, op="max")
-            cand = jnp.where(eff == m_all[:, None], gid[None, :],
-                             jnp.int32(_I32MAX)).min(axis=-1)
-            sampled = tpc.all_reduce(cand, op="min")
-            # ---- teacher-force prefill, advance the lanes
-            nxt_p = jnp.take_along_axis(
-                prompts, jnp.clip(pos + 1, 0, P_max - 1)[:, None],
-                axis=1)[:, 0]
-            nxt = jnp.where(pos + 1 < plen, nxt_p, sampled)
-            toks = jnp.where(active, nxt, toks)
-            pos = jnp.where(active, pos + 1, pos)
-            return sampled, toks, pos, active, prompts, pcache
-
-        pspec = param_specs(cfg, topo)
-        cspec = paged_cache_specs(cfg, topo, plan, pplan)
-        rep = P()
-        fn = compat.shard_map(
-            step_shard, mesh=topo.cube.mesh,
-            in_specs=(pspec, cspec) + (rep,) * 13,
-            out_specs=(rep, rep, rep, rep, rep, cspec),
-            check_vma=False)
-        return jax.jit(fn, donate_argnums=(1,))
+        self._step_fn = make_step(cfg, topo, plan, self.pplan)
 
     # ------------------------------------------------------------- admission
     def submit(self, req: Request) -> None:
@@ -563,4 +568,4 @@ def poisson_trace(n_requests: int, *, rate: float, plen_range=(4, 16),
     return reqs
 
 
-__all__ = ["Request", "ServeEngine", "poisson_trace"]
+__all__ = ["Request", "ServeEngine", "make_step", "poisson_trace"]

@@ -17,17 +17,21 @@ reference:
     contiguous slot range contains it (``owner(j) = j // blocks_per_shard``).
     Allocation never crosses that boundary, so each shard can materialize its
     exact contiguous ``(B, S_loc, ...)`` cache view from purely local pages;
-  * the view gather zero-fills unallocated blocks, matching the zero-init of
-    the contiguous cache; stale data in a *reallocated* page sits at key
+  * each shard's pool carries one extra **scratch** page, which unallocated
+    blocks read.  It starts zero and decode never writes it: a row whose
+    block is unallocated (an idle batch lane) or owned by another shard is
+    dropped.  So the view reads zeros there, matching the zero-init of the
+    contiguous cache; stale data in a *reallocated* page sits at key
     positions the flash-decode mask already excludes (causality / ``dk >= 0``
-    under rolling), so it never reaches a logit;
-  * each shard's pool carries one extra **scratch** page: masked writes (a
-    slot whose block is unallocated -- e.g. an idle batch lane) land there
-    instead of scatter-aliasing a live page.
+    under rolling), so it never reaches a logit.
 
-``PagedServer.decode_shard`` is therefore gather-view -> the *unchanged*
-``Server.decode_shard`` flash-decode cell -> scatter-back, and the bf16
-differential test asserts bitwise equality against the contiguous path.
+``PagedServer.decode_shard`` runs the *unchanged* ``Server.decode_shard``
+flash-decode cell with two per-layer hooks: inside the layer scan, each
+layer gathers only its own view from the pools; the scan emits only the
+row each lane wrote at that layer, and one scatter after the scan writes
+those rows into the pools at ``(page, slot % page_size)``.  No whole-cache
+view is ever materialised, and the bf16 differential test asserts bitwise
+equality against the contiguous path.
 
 Page exchange across the cube boundary (preemption/swap in the engine, or
 any host-mediated migration) is the rooted-collective pair of paper
@@ -46,6 +50,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.models.blocks import decode_slot
 from repro.models.config import ModelConfig
 from repro.models.serving import ServePlan, Server, cache_defs
 from repro.models.topology import Topology
@@ -70,7 +75,8 @@ class PagePlan:
 
     @property
     def pool_pages(self) -> int:
-        """Physical page-axis extent per shard (usable + 1 scratch)."""
+        """Physical page-axis extent per shard (usable + 1 scratch, which
+        stays zero)."""
         return self.pages_per_shard + 1
 
     @property
@@ -220,11 +226,11 @@ class PageTable:
         return self.table.copy()
 
 
-# ------------------------------------------ per-shard gather/scatter view
+# ------------------------------------------ per-shard layer view, row write
 def local_block_ids(pplan: PagePlan, table: Array, shard: Array | int):
     """This shard's slice of the table: (safe local page ids, valid mask),
     both ``(B, blocks_per_shard)``.  Unallocated blocks map to the scratch
-    page so gathers/scatters stay branch-free."""
+    page so the view's gather stays branch-free."""
     myt = lax.dynamic_slice_in_dim(
         table, shard * pplan.blocks_per_shard, pplan.blocks_per_shard,
         axis=1)
@@ -233,36 +239,47 @@ def local_block_ids(pplan: PagePlan, table: Array, shard: Array | int):
     return safe, valid
 
 
-def gather_view(pool: Array, safe: Array, valid: Array,
+def gather_view(pool: Array, layer: Array | int, safe: Array,
                 pplan: PagePlan) -> Array:
-    """Local pool ``(n_units, pool_pages, page, *tail)`` -> the shard's
-    contiguous cache view ``(n_units, B, S_loc, *tail)``.  Unallocated
-    blocks read as zeros (identical to the contiguous zero-init)."""
-    B, bps = safe.shape
-    tail = pool.shape[3:]
-    g = jnp.take(pool, safe.reshape(-1), axis=1)
-    g = g.reshape((pool.shape[0], B, bps, pplan.page_size) + tail)
-    vm = valid.reshape((1, B, bps, 1) + (1,) * len(tail))
-    g = jnp.where(vm, g, jnp.zeros((), pool.dtype))
-    return g.reshape((pool.shape[0], B, pplan.S_loc) + tail)
+    """One unit of the local pool ``(n_units, pool_pages, page, *tail)`` ->
+    the shard's contiguous cache view of that unit ``(B, S_loc, *tail)``.
+    Unallocated blocks read the scratch page, which decode never writes,
+    so they read as zeros (identical to the contiguous zero-init)."""
+    g = pool[layer, safe.reshape(-1)]
+    return g.reshape((safe.shape[0], pplan.S_loc) + pool.shape[3:])
 
 
-def scatter_view(pool: Array, view: Array, safe: Array,
-                 pplan: PagePlan) -> Array:
-    """Write an updated contiguous view back into the local pool.  Blocks of
-    unallocated slots route to the scratch page (never read); allocated page
-    ids are unique by construction, so the scatter never aliases."""
-    B, bps = safe.shape
-    tail = pool.shape[3:]
-    blocks = view.reshape((pool.shape[0], B * bps, pplan.page_size) + tail)
-    return pool.at[:, safe.reshape(-1)].set(blocks)
+def row_targets(pplan: PagePlan, safe: Array, valid: Array, idx: Array,
+                in_rng: Array):
+    """Where each lane's new row lands in the local pool: (page id, offset
+    within the page), both ``(B,)``, from the lane's clipped local slot
+    ``idx`` and whether its slot is on this shard (``in_rng``).  A row whose
+    slot another shard owns, or whose block is unallocated (an idle lane),
+    gets the page id ``pool_pages``, past the pool, and is dropped."""
+    bidx = jnp.arange(safe.shape[0])
+    blk = idx // pplan.page_size
+    page = jnp.where(in_rng & valid[bidx, blk], safe[bidx, blk],
+                     pplan.pool_pages)
+    return page, idx % pplan.page_size
+
+
+def write_rows(pool: Array, rows: Array, page: Array, off: Array) -> Array:
+    """Write one row per lane and unit ``(n_units, B, *tail)`` into the
+    local pool at ``(page, off)``, in place; rows whose page lies past the
+    pool are dropped.  Allocated page ids are unique by construction, so no
+    two rows collide.  The unit axis is indexed too, so every indexed axis
+    leads and no backend transposes the pool to scatter into it."""
+    units = jnp.arange(pool.shape[0])[:, None]
+    return pool.at[units, page[None], off[None]].set(rows, mode="drop")
 
 
 class PagedServer:
-    """Paged decode cell: gather-view -> ``Server.decode_shard`` (unchanged
-    flash-decode arithmetic) -> scatter-back.  Per-shard function; wrap in
-    ``shard_map`` with ``paged_cache_specs`` for the cache and a replicated
-    spec for the page table."""
+    """Paged decode cell: ``Server.decode_shard`` (unchanged flash-decode
+    arithmetic) with hooks that gather each layer's view from the page pools
+    inside the layer scan and emit only the row each lane wrote; one
+    scatter after the scan writes those rows into the pools.  Per-shard
+    function; wrap in ``shard_map`` with ``paged_cache_specs`` for the cache
+    and a replicated spec for the page table."""
 
     def __init__(self, server: Server, pplan: PagePlan):
         self.server = server
@@ -272,24 +289,40 @@ class PagedServer:
         """One paged decode step. ``table``: (B, n_blocks) int32 replicated.
         Returns (logits, new paged cache)."""
         pplan = self.pplan
-        plan = self.server.plan
+        cfg, plan = self.server.cfg, self.server.plan
         me = lax.axis_index(plan.kv_axes)
         safe, valid = local_block_ids(pplan, table, me)
-        view = {}
+        _, idx, in_rng = decode_slot(pos, pplan.S_loc, pplan.n_shards,
+                                     plan.kv_axes,
+                                     plan.S_cache < plan.S_ctx)
+        bidx = jnp.arange(idx.shape[0])
+
+        # the scan's per-unit input for a pooled leaf is the unit's index
+        # into the pool: no slice of the pool is ever materialised
+        units = jnp.arange(cfg.n_layers // cfg.unit())
+        xs = {pkey: {k: units if k in PAGED_KEYS else leaf
+                     for k, leaf in d.items()}
+              for pkey, d in pcache.items()}
+
+        def layer_cache(key, cin):
+            return {k: gather_view(pcache[key][k], v, safe, pplan)
+                    if k in PAGED_KEYS else v for k, v in cin.items()}
+
+        def layer_out(key, c):
+            return {k: v[bidx, idx] if k in PAGED_KEYS else v
+                    for k, v in c.items()}
+
+        logits, out = self.server.decode_shard(
+            params, xs, tokens, pos, layer_cache=layer_cache,
+            layer_out=layer_out)
+        page, off = row_targets(pplan, safe, valid, idx, in_rng)
+        new = {}
         for pkey, d in pcache.items():
-            view[pkey] = {
-                k: gather_view(leaf, safe, valid, pplan)
-                if k in PAGED_KEYS else leaf
+            new[pkey] = {
+                k: write_rows(leaf, out[pkey][k], page, off)
+                if k in PAGED_KEYS else out[pkey][k]
                 for k, leaf in d.items()}
-        logits, new_view = self.server.decode_shard(params, view, tokens,
-                                                    pos)
-        out = {}
-        for pkey, d in pcache.items():
-            out[pkey] = {
-                k: scatter_view(leaf, new_view[pkey][k], safe, pplan)
-                if k in PAGED_KEYS else new_view[pkey][k]
-                for k, leaf in d.items()}
-        return logits, out
+        return logits, new
 
 
 # --------------------------------------------- cross-cube page exchange
@@ -324,7 +357,7 @@ def extract_slot_pages(pcache, table_row: np.ndarray, slot: int,
             if k in PAGED_KEYS:
                 taken = jnp.take(leaf, gidx, axis=1)
                 host = np.array(kvc.gather(taken))
-                host[:, ~valid] = 0          # scratch content is garbage
+                host[:, ~valid] = 0          # no page holds these blocks
                 pages[(pkey, k)] = host
             else:
                 rows[(pkey, k)] = np.array(kvc.gather(leaf[:, slot]))
@@ -358,5 +391,5 @@ __all__ = [
     "PAGED_KEYS", "PagePlan", "PageTable", "PagedServer",
     "extract_slot_pages", "gather_view", "init_paged_cache",
     "inject_slot_pages", "local_block_ids", "make_page_plan",
-    "paged_cache_defs", "paged_cache_specs", "scatter_view",
+    "paged_cache_defs", "paged_cache_specs", "row_targets", "write_rows",
 ]

@@ -70,7 +70,8 @@ def paged_view(pool: np.ndarray, table: np.ndarray, shard: int,
     """Reference for ``pages.gather_view``: one shard's local pool
     ``(n_units, pool_pages, page_size, *tail)`` plus the **global** table
     ``(B, n_blocks)`` -> that shard's contiguous ``(n_units, B, S_loc, *tail)``
-    cache view, zeros where a block is unallocated."""
+    cache view, zeros where a block is unallocated (``gather_view`` gives
+    one unit of it)."""
     n_units = pool.shape[0]
     tail = pool.shape[3:]
     B = table.shape[0]

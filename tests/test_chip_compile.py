@@ -1,7 +1,7 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
-kernels at real widths, and the qwen3-1.7b decode step at published
-widths with one layer (the train step takes ~20 s to compile, too long to
-keep here). What the chip's compiler refuses (an unaligned tile, a
+kernels at real widths, the qwen3-1.7b decode step at published widths
+with one layer, and the serve engine's paged step at two and four layers
+(the train step takes ~20 s to compile, too long to keep here). What the chip's compiler refuses (an unaligned tile, a
 primitive with no kernel lowering, a program over the 16 GB of HBM) fails
 here, at no chip time. Nothing runs, so these say nothing about results
 or speed.
@@ -120,3 +120,60 @@ def test_qwen3_one_layer_decode_step_compiles(v5e):
     compiled = make_decode_step(cfg, topo, plan).lower(
         params, cache_structs(cfg, topo, plan), tok, tok).compile()
     _fits(compiled)
+
+
+def _engine_step_temp(v5e, n_layers):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro import configs
+    from repro.launch.serve import serve_topology
+    from repro.models.params import param_structs
+    from repro.models.serving import make_serve_plan
+    from repro.serving.engine import make_step
+    from repro.serving.pages import make_page_plan, paged_cache_defs
+    B, S_ctx = 32, 1024
+    cfg = dataclasses.replace(configs.get("qwen3-1.7b"), n_layers=n_layers)
+    topo = serve_topology(cfg, devices=v5e.devices[:1])
+    plan = make_serve_plan(cfg, topo, S_ctx=S_ctx, global_batch=B)
+    pplan = make_page_plan(plan, topo, page_size=4)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=s.sharding),
+        param_structs(cfg, topo))
+    pools = jax.tree.map(
+        lambda d: jax.ShapeDtypeStruct(d[0], d[2],
+                                       sharding=topo.cube.sharding(d[1])),
+        paged_cache_defs(cfg, topo, plan, pplan),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+    rep = topo.cube.sharding(P())
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+
+    args = (params, pools, s((B, pplan.n_blocks)), s((B,)), s((B,)),
+            s((B,), jnp.bool_), s((B, S_ctx)), s((B,), jnp.bool_),
+            s((B,)), s((B,)), s((B, S_ctx)), s((B,)), s((B,), jnp.bool_),
+            s((B,), jnp.float32), s((2,), jnp.uint32))
+    compiled = make_step(cfg, topo, plan, pplan).lower(*args).compile()
+    _fits(compiled)
+    # one layer's k+v view of every lane, bf16
+    view = 2 * B * plan.S_cache * cfg.n_kv_heads * cfg.head_dim * 2
+    return compiled.memory_analysis().temp_size_in_bytes, view
+
+
+def test_qwen3_engine_step_temp_is_one_layer(v5e, monkeypatch):
+    """The serve engine's paged step at the serve cell's 32 lanes, S_ctx
+    1024, page 4: it gathers one layer's view at a time and writes one row
+    per lane, so its temporary stays under 1 GB and two more layers add
+    less than one layer's view (the whole-view gather took 7.65 GB at 28
+    layers). It computes in bfloat16, as served, whatever compute dtype
+    another test module has set."""
+    import jax.numpy as jnp
+    from repro.models import blocks, lm, params
+    for mod in (params, blocks, lm):
+        monkeypatch.setattr(mod, "COMPUTE_DTYPE", jnp.bfloat16)
+    t2, view = _engine_step_temp(v5e, 2)
+    t4, _ = _engine_step_temp(v5e, 4)
+    assert t2 < 2 ** 30, t2
+    assert t4 - t2 < view, (t2, t4, view)
