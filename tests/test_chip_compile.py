@@ -79,6 +79,32 @@ def test_flash_kernel_compiles(one_chip, B, S, H, KV, hd, causal, window):
     _fits(compiled)
 
 
+# (B, S, H, KV, hd, window, temp limit in bytes): phi3-mini-4k's training
+# attention (full MHA at head_dim 96, banded at window 2047; XLA's own VJP
+# of the blockwise forward took 3.78 GB) and qwen3-1.7b's at the train
+# cell's 4 rows (GQA, causal; 4.44 GB before)
+@pytest.mark.parametrize("B,S,H,KV,hd,window,limit", [
+    (1, 4096, 32, 32, 96, 2047, 0.5e9),
+    (4, 2048, 16, 8, 128, -1, 0.8e9),
+])
+def test_training_attention_grad_compiles(one_chip, B, S, H, KV, hd, window,
+                                          limit):
+    """jax.grad of the training attention: the FlashAttention-2 backward
+    keeps no score matrix, so its temporary stays under ``limit``."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models.layers import chunked_attention
+    q, k, v = _structs(one_chip, ((B, S, H, hd), jnp.bfloat16),
+                       ((B, S, KV, hd), jnp.bfloat16),
+                       ((B, S, KV, hd), jnp.bfloat16))
+    compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(chunked_attention(
+        q, k, v, causal=True, window=window).astype(jnp.float32)),
+        (0, 1, 2))).lower(q, k, v).compile()
+    _fits(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < limit, temp
+
+
 @pytest.mark.parametrize("chunk", [32, 64])
 def test_rwkv6_kernel_compiles(one_chip, chunk):
     """rwkv6-7b: d_model 4096 in heads of 64."""
