@@ -28,7 +28,7 @@ from repro.core.hypercube import Hypercube
 from repro.models import ssm
 from repro.models.config import ModelConfig, FULL_WINDOW
 from repro.models.layers import (
-    rms_norm, rope, chunked_attention, NEG_INF)
+    rms_norm, rope, chunked_attention, decode_mask, NEG_INF)
 from repro.models.params import kv_is_sharded, dt_rank, COMPUTE_DTYPE
 from repro.models.topology import Topology
 
@@ -186,16 +186,22 @@ def attn_decode(cfg: ModelConfig, topo: Topology, w: dict, x: Array,
     pos: (B,) int32 per-request positions. ``rolling``: cache length <
     context (sliding window), slot = pos % S_cache.
     Returns (out (B, D), updated cache dict).
+
+    Self-attention may instead find the layer's K/V left in the page pools
+    under ``c["pages"]`` (:class:`repro.serving.pages.PagedLayer`): its
+    ``attend`` gives this shard's partial over each lane's keys below its
+    position, the current key is merged into it here, and ``c[keys]`` come
+    back as the lanes' new rows for the caller to write.
     """
     kk, vk = keys
-    cache_k, cache_v = c[kk], c[vk]
+    pages = None if cross else c.get("pages")
+    cache_k, cache_v = c.get(kk), c.get(vk)
     int8_cache = (kk + "_s") in c
     tpc = topo.comm(topo.tp)
     kvc = topo.comm(kv_axes)
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     B = x.shape[0]
     hn = rms_norm(x[:, None], w[prefix + "ln"], cfg.norm_eps)  # (B,1,D)
-    t = topo.tp_size
 
     # q: local columns -> gather flat then reshape (supports tp > heads)
     q = hn @ w[prefix + "wq"]                                  # (B,1,cols)
@@ -214,10 +220,15 @@ def attn_decode(cfg: ModelConfig, topo: Topology, w: dict, x: Array,
 
         # write into my cache chunk
         n_shards = topo.size(kv_axes)
-        S_loc = cache_k.shape[1]
+        S_loc = cache_k.shape[1] if pages is None else pages.S_loc
         S_cache = S_loc * n_shards
         my_lo, idx, in_rng = decode_slot(pos, S_loc, n_shards, kv_axes,
                                          rolling)
+        if pages is not None:          # the pools are the caller's to write
+            o, k_row, v_row = _attend_pages(pages, q, k_new, v_new, pos,
+                                            in_rng, window, kvc)
+            return (_decode_out_proj(cfg, topo, w, x, o, prefix),
+                    {**c, kk: k_row, vk: v_row})
         bidx = jnp.arange(B)
         if int8_cache:
             ks = jnp.maximum(jnp.abs(k_new).max(-1), 1e-6) / 127.0
@@ -259,32 +270,77 @@ def attn_decode(cfg: ModelConfig, topo: Topology, w: dict, x: Array,
     if cross:
         ok = jnp.ones_like(s, bool)
     else:
-        dq = pos[:, None, None]
-        dk = k_pos[:, None, :]
-        ok = (dk <= dq) & (dk >= 0)
-        wnd = jnp.asarray(window)
-        ok &= jnp.where(wnd < 0, True, (dq - dk) < wnd)
+        ok = decode_mask(pos[:, None, None], k_pos[:, None, :], window)
     s = jnp.where(ok, s, NEG_INF)
-    m = s.max(axis=-1)                                         # (B,H)
-    m_all = kvc.all_reduce(m, op="max")
-    p = jnp.exp(s - m_all[..., None])
-    l = kvc.all_reduce(p.sum(-1))
-    vf = cache_v.astype(jnp.float32)
-    if int8_cache:
-        vf = vf * c[vk + "_s"][..., None]
-    o = _decode_out(p, vf, G)                                  # (B,H,hd)
-    o = kvc.all_reduce(o) / jnp.maximum(l, 1e-30)[..., None]
 
+    def weights(m_all):
+        p = jnp.exp(s - m_all[..., None])
+        return p, p.sum(-1)
+
+    def values(p):
+        vf = cache_v.astype(jnp.float32)
+        if int8_cache:
+            vf = vf * c[vk + "_s"][..., None]
+        return _decode_out(p, vf, G)                           # (B,H,hd)
+
+    o = _lse_combine(kvc, s.max(axis=-1), weights, values)
+    c = dict(c)
+    c[kk], c[vk] = cache_k, cache_v
+    return _decode_out_proj(cfg, topo, w, x, o, prefix), c
+
+
+def _decode_out_proj(cfg, topo, w, x, o, prefix):
+    """x plus the attention output ``o`` (B, H, hd) projected out."""
+    B, H, hd = o.shape
     # out projection: my slice of the flattened head dim (wo row shard)
     me = _tp_rank(topo)
-    rows = (H * hd) // t
+    rows = (H * hd) // topo.tp_size
     o_flat = o.reshape(B, H * hd).astype(COMPUTE_DTYPE)
     o_loc = lax.dynamic_slice_in_dim(o_flat, me * rows, rows, axis=1)
     out = o_loc @ w[prefix + "wo"]
-    out = tpc.all_reduce(out)
-    c = dict(c)
-    c[kk], c[vk] = cache_k, cache_v
-    return x + out.astype(x.dtype), c
+    out = topo.comm(topo.tp).all_reduce(out)
+    return x + out.astype(x.dtype)
+
+
+def _attend_pages(pages, q, k_new, v_new, pos, in_rng, window, kvc):
+    """Attention of each lane's query over the keys its pages hold on this
+    shard (the kernel's partial, positions below the lane's) and its own
+    new key (on the shard whose slots hold it), LSE-combined over the kv
+    shards. Returns (output, the new K and V rows in the pools' dtype)."""
+    B, _, H, hd = q.shape
+    G = H // k_new.shape[1]
+    qf = q.reshape(B, H, hd).astype(jnp.float32) * hd ** -0.5
+    k_row = k_new.astype(pages.k.dtype)
+    v_row = v_new.astype(pages.v.dtype)
+    o_p, m_p, l_p = pages.attend(qf, window)
+    s = _decode_scores(qf, k_row[:, None].astype(jnp.float32), G)[..., 0]
+    ok = in_rng[:, None] & decode_mask(pos[:, None], pos[:, None], window)
+    s = jnp.where(ok, s, NEG_INF)                              # (B,H)
+
+    def weights(m_all):
+        a, e = jnp.exp(m_p - m_all), jnp.exp(s - m_all)
+        return (a, e), l_p * a + e
+
+    def values(ae):
+        a, e = ae
+        own = _decode_out(e[..., None], v_row[:, None].astype(jnp.float32),
+                          G)
+        return o_p * a[..., None] + own
+
+    o = _lse_combine(kvc, jnp.maximum(m_p, s), weights, values)
+    return o, k_row, v_row
+
+
+def _lse_combine(kvc, m, weights, values):
+    """Flash-decode combine over the kv shards of ``kvc``: ``m`` (B, H) is
+    this shard's max score; ``weights(m_all)`` gives its softmax numerators
+    against the max over every shard and their sum (B, H), and
+    ``values(weights)`` their weighted values (B, H, hd). Returns the
+    normalised output (B, H, hd)."""
+    m_all = kvc.all_reduce(m, op="max")
+    p, l = weights(m_all)
+    l = kvc.all_reduce(l)
+    return kvc.all_reduce(values(p)) / jnp.maximum(l, 1e-30)[..., None]
 
 
 def decode_slot(pos: Array, S_loc: int, n_shards: int, kv_axes,

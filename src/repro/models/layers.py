@@ -109,6 +109,16 @@ def _mask(q_pos: Array, k_pos: Array, causal: bool, window) -> Array:
     return ok
 
 
+def decode_mask(dq, dk, window):
+    """Which keys a decoding query sees: key positions ``dk`` that are not
+    negative, not after the query's ``dq``, and within ``window`` of it
+    (negative: no window; a python int or traced scalar). Broadcasts.
+    ``attn_decode`` and the paged decode kernel both mask with it."""
+    ok = (dk <= dq) & (dk >= 0)
+    wnd = jnp.asarray(window)
+    return ok & jnp.where(wnd < 0, True, (dq - dk) < wnd)
+
+
 def chunked_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                       window=-1, q_offset=0, k_offset=0,
                       chunk: int = 1024, partial: bool = False):
@@ -415,19 +425,6 @@ def _attention_bwd(st, res, g):
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
-
-
-def finish_partial_attention(acc, m, l, *, comm, B, Sq, H, hd, dtype):
-    """LSE-combine ``partial=True`` results across the shards of ``comm``
-    (a :class:`repro.core.comm.Communicator` bound to the flash-decode
-    axes)."""
-    m_max = comm.all_reduce(m, op="max")
-    w = jnp.exp(m - m_max)
-    acc = comm.all_reduce(acc * w[..., None])
-    l = comm.all_reduce(l * w)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = jnp.moveaxis(out, 3, 1).reshape(B, Sq, H, hd)
-    return out.astype(dtype)
 
 
 def reference_attention(q, k, v, *, causal=True, window=-1, q_offset=0,

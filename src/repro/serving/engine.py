@@ -13,10 +13,10 @@ One engine step serves every in-flight request at once and costs exactly:
     profile;
   * **one jitted shard_map step** (:func:`make_step`) wrapping the paged
     flash-decode cell (:class:`repro.serving.pages.PagedServer`: the
-    unchanged ``Server.decode_shard`` arithmetic on each layer's view,
-    gathered from the page pools inside the layer scan, with one row per
-    lane written back after it) plus device-side sampling, so no logits
-    ever cross to the host.
+    ``Server.decode_shard`` cell reading each layer's keys from the page
+    pools inside the layer scan, in place through the paged decode kernel
+    on TPU, with one row per lane written back after it) plus
+    device-side sampling, so no logits ever cross to the host.
 
 Scheduling is continuous batching with slot reuse: requests admit from the
 arrival queue into free batch lanes, prefill runs *through the decode cell*
@@ -71,6 +71,7 @@ from repro.telemetry.metrics import MetricsRegistry
 
 Array = jax.Array
 _I32MAX = np.int32(np.iinfo(np.int32).max)
+SHARE_BUCKETS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0)
 
 
 @dataclasses.dataclass
@@ -221,6 +222,9 @@ class ServeEngine:
         self._lower_lookups = 0
 
         self._step_fn = make_step(cfg, topo, plan, self.pplan)
+        self._paged_kernel = float(any(
+            pages_mod.reads_in_place(topo, plan, d["k"])
+            for d in self.pcache.values() if "k" in d))
 
     # ------------------------------------------------------------- admission
     def submit(self, req: Request) -> None:
@@ -475,6 +479,10 @@ class ServeEngine:
         total_pages = self.pplan.n_shards * self.pplan.pages_per_shard
         self.metrics.gauge("serve.page_occupancy").set(
             1.0 - float(free.sum()) / total_pages if total_pages else 0.0)
+        self.metrics.gauge("serve.paged_kernel").set(self._paged_kernel)
+        self.metrics.histogram(
+            "serve.kv_read_share", buckets=SHARE_BUCKETS).observe(
+            self.table.read_share(self.pos_h))
         return admit, admit_tok, admit_pos, admit_prompts
 
     def _mirror(self, t0: float) -> None:

@@ -26,12 +26,18 @@ reference:
     under rolling), so it never reaches a logit.
 
 ``PagedServer.decode_shard`` runs the *unchanged* ``Server.decode_shard``
-flash-decode cell with two per-layer hooks: inside the layer scan, each
-layer gathers only its own view from the pools; the scan emits only the
-row each lane wrote at that layer, and one scatter after the scan writes
-those rows into the pools at ``(page, slot % page_size)``.  No whole-cache
-view is ever materialised, and the bf16 differential test asserts bitwise
-equality against the contiguous path.
+flash-decode cell with two per-layer hooks: inside the layer scan each
+layer reads its keys from the pools; the scan emits only the row each lane
+wrote at that layer, and one scatter after the scan writes those rows into
+the pools at ``(page, slot % page_size)``.  No whole-cache view is ever
+materialised.  Where the pools live on TPU, hold the compute dtype and the
+cache is not rolling (:func:`reads_in_place`), a layer's keys are read in
+place by the paged decode kernel
+(``repro.kernels.attention.paged_decode``), which walks only each lane's
+allocated blocks below its position.  Elsewhere (the int8 layout, rolling
+caches, every other backend) each layer gathers its ``(B, S_loc)`` view,
+and the bf16 differential test asserts bitwise equality against the
+contiguous path.
 
 Page exchange across the cube boundary (preemption/swap in the engine, or
 any host-mediated migration) is the rooted-collective pair of paper
@@ -43,6 +49,7 @@ for the per-request recurrent-state rows (SSM/RWKV) that are not paged.
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +57,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.attention.paged_decode import (
+    PageWalk, fits, page_walk, paged_decode_attention)
 from repro.models.blocks import decode_slot
 from repro.models.config import ModelConfig
 from repro.models.serving import ServePlan, Server, cache_defs
@@ -221,6 +230,16 @@ class PageTable:
         return all(f >= n for f, n in zip(self.free_per_shard(),
                                           self.blocks_needed(n_positions)))
 
+    def read_share(self, pos: np.ndarray) -> float:
+        """The share of all lanes' blocks that the paged decode kernel
+        reads at cache positions ``pos`` (B,): allocated blocks holding a
+        slot below the lane's position."""
+        pp = self.pplan
+        upto = -(-np.minimum(pos, pp.n_blocks * pp.page_size)
+                 // pp.page_size)
+        read = (self.table >= 0) & (np.arange(pp.n_blocks) < upto[:, None])
+        return float(read.sum()) / read.size
+
     def array(self) -> np.ndarray:
         """Snapshot for the per-step replicated broadcast."""
         return self.table.copy()
@@ -273,13 +292,44 @@ def write_rows(pool: Array, rows: Array, page: Array, off: Array) -> Array:
     return pool.at[units, page[None], off[None]].set(rows, mode="drop")
 
 
+def reads_in_place(topo: Topology, plan: ServePlan, pool) -> bool:
+    """Whether paged decode reads the K/V pool leaf ``pool`` in place with
+    the paged decode kernel: the pools live on TPU devices, hold the
+    compute dtype (not the int8 layout with its scales) in pages the chip
+    can DMA whole, and the cache is not rolling, so a slot is a position.
+    Every other case gathers each layer's view."""
+    return (topo.cube.mesh.devices.flat[0].platform == "tpu"
+            and plan.cache_dtype == "bf16" and plan.S_cache == plan.S_ctx
+            and fits(pool.shape[3], pool.shape[4], pool.dtype))
+
+
+class PagedLayer(NamedTuple):
+    """One attention layer's K and V left in the page pools, as
+    ``attn_decode`` finds them under ``c["pages"]``: this shard's whole pool
+    leaves, the unit to read, the step's walk of every lane's blocks and
+    the shard's slot extent."""
+    k: Array
+    v: Array
+    layer: Array
+    walk: PageWalk
+    S_loc: int
+
+    def attend(self, qf: Array, window):
+        """This shard's partial ``(o, m, l)`` of the scaled queries ``qf``
+        (B, H, hd) over each lane's keys below its position."""
+        return paged_decode_attention(qf, self.k, self.v, self.layer,
+                                      window, self.walk)
+
+
 class PagedServer:
     """Paged decode cell: ``Server.decode_shard`` (unchanged flash-decode
-    arithmetic) with hooks that gather each layer's view from the page pools
-    inside the layer scan and emit only the row each lane wrote; one
-    scatter after the scan writes those rows into the pools.  Per-shard
-    function; wrap in ``shard_map`` with ``paged_cache_specs`` for the cache
-    and a replicated spec for the page table."""
+    arithmetic) with hooks that read each layer's keys from the page pools
+    inside the layer scan, in place with the paged decode kernel where
+    :func:`reads_in_place` holds and from a gathered view elsewhere, and
+    emit only the row each lane wrote; one scatter after the scan writes
+    those rows into the pools.  Per-shard function; wrap in ``shard_map``
+    with ``paged_cache_specs`` for the cache and a replicated spec for the
+    page table."""
 
     def __init__(self, server: Server, pplan: PagePlan):
         self.server = server
@@ -292,9 +342,9 @@ class PagedServer:
         cfg, plan = self.server.cfg, self.server.plan
         me = lax.axis_index(plan.kv_axes)
         safe, valid = local_block_ids(pplan, table, me)
-        _, idx, in_rng = decode_slot(pos, pplan.S_loc, pplan.n_shards,
-                                     plan.kv_axes,
-                                     plan.S_cache < plan.S_ctx)
+        my_lo, idx, in_rng = decode_slot(pos, pplan.S_loc, pplan.n_shards,
+                                         plan.kv_axes,
+                                         plan.S_cache < plan.S_ctx)
         bidx = jnp.arange(idx.shape[0])
 
         # the scan's per-unit input for a pooled leaf is the unit's index
@@ -303,12 +353,24 @@ class PagedServer:
         xs = {pkey: {k: units if k in PAGED_KEYS else leaf
                      for k, leaf in d.items()}
               for pkey, d in pcache.items()}
+        in_place = {key: reads_in_place(self.server.topo, plan, d["k"])
+                    for key, d in pcache.items() if "k" in d}
+        if any(in_place.values()):
+            walk = page_walk(jnp.where(valid, safe, -1), pos, my_lo,
+                             pplan.page_size)
 
         def layer_cache(key, cin):
+            if in_place.get(key):
+                c = {k: v for k, v in cin.items() if k not in PAGED_KEYS}
+                c["pages"] = PagedLayer(pcache[key]["k"], pcache[key]["v"],
+                                        cin["k"], walk, pplan.S_loc)
+                return c
             return {k: gather_view(pcache[key][k], v, safe, pplan)
                     if k in PAGED_KEYS else v for k, v in cin.items()}
 
         def layer_out(key, c):
+            if "pages" in c:       # attn_decode left the new rows in k, v
+                return {k: v for k, v in c.items() if k != "pages"}
             return {k: v[bidx, idx] if k in PAGED_KEYS else v
                     for k, v in c.items()}
 
@@ -388,8 +450,9 @@ def inject_slot_pages(pcache, saved: dict, table_row: np.ndarray, slot: int,
 
 
 __all__ = [
-    "PAGED_KEYS", "PagePlan", "PageTable", "PagedServer",
+    "PAGED_KEYS", "PagePlan", "PageTable", "PagedLayer", "PagedServer",
     "extract_slot_pages", "gather_view", "init_paged_cache",
     "inject_slot_pages", "local_block_ids", "make_page_plan",
-    "paged_cache_defs", "paged_cache_specs", "row_targets", "write_rows",
+    "paged_cache_defs", "paged_cache_specs", "reads_in_place", "row_targets",
+    "write_rows",
 ]

@@ -126,6 +126,13 @@ DECLARED: dict[str, tuple[str, str]] = {
                             "submit() to its first admission into a lane "
                             "(re-admissions after preemption not "
                             "counted)"),
+    "serve.kv_read_share": ("histogram", "Per step: the KV blocks the "
+                            "paged decode kernel reads (each lane's "
+                            "allocated blocks below its position) over "
+                            "lanes x blocks per lane"),
+    "serve.paged_kernel": ("gauge", "1 when the step reads K/V in place "
+                           "with the paged decode kernel, 0 when each "
+                           "layer gathers its view"),
     # checkpoint/manager.py -- elastic checkpoint subsystem
     "ckpt.saves": ("counter", "Checkpoint saves dispatched"),
     "ckpt.restores": ("counter", "Checkpoint restores completed "

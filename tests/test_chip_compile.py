@@ -148,7 +148,7 @@ def test_qwen3_one_layer_decode_step_compiles(v5e):
     _fits(compiled)
 
 
-def _engine_step_temp(v5e, n_layers):
+def _compiled_engine_step(v5e, n_layers):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -183,23 +183,46 @@ def _engine_step_temp(v5e, n_layers):
             s((B,), jnp.float32), s((2,), jnp.uint32))
     compiled = make_step(cfg, topo, plan, pplan).lower(*args).compile()
     _fits(compiled)
-    # one layer's k+v view of every lane, bf16
-    view = 2 * B * plan.S_cache * cfg.n_kv_heads * cfg.head_dim * 2
-    return compiled.memory_analysis().temp_size_in_bytes, view
+    return compiled
 
 
-def test_qwen3_engine_step_temp_is_one_layer(v5e, monkeypatch):
-    """The serve engine's paged step at the serve cell's 32 lanes, S_ctx
-    1024, page 4: it gathers one layer's view at a time and writes one row
-    per lane, so its temporary stays under 1 GB and two more layers add
-    less than one layer's view (the whole-view gather took 7.65 GB at 28
-    layers). It computes in bfloat16, as served, whatever compute dtype
+# one layer's k+v view of every lane at the serve cell's shape, bf16
+VIEW_BYTES = 2 * 32 * 1024 * 8 * 128 * 2
+
+
+@pytest.fixture
+def bf16_compute(monkeypatch):
+    """The step computes in bfloat16, as served, whatever compute dtype
     another test module has set."""
     import jax.numpy as jnp
     from repro.models import blocks, lm, params
     for mod in (params, blocks, lm):
         monkeypatch.setattr(mod, "COMPUTE_DTYPE", jnp.bfloat16)
-    t2, view = _engine_step_temp(v5e, 2)
-    t4, _ = _engine_step_temp(v5e, 4)
+
+
+def test_qwen3_engine_step_temp_is_one_layer(v5e, bf16_compute):
+    """The serve engine's paged step at the serve cell's 32 lanes, S_ctx
+    1024, page 4: it reads one layer's keys at a time and writes one row
+    per lane, so its temporary stays under 1 GB and two more layers add
+    less than one layer's view (the whole-view gather took 7.65 GB at 28
+    layers)."""
+    t2 = _compiled_engine_step(v5e, 2).memory_analysis().temp_size_in_bytes
+    t4 = _compiled_engine_step(v5e, 4).memory_analysis().temp_size_in_bytes
     assert t2 < 2 ** 30, t2
-    assert t4 - t2 < view, (t2, t4, view)
+    assert t4 - t2 < VIEW_BYTES, (t2, t4, VIEW_BYTES)
+
+
+def test_qwen3_engine_step_reads_pages_in_place(v5e, bf16_compute):
+    """On the chip the engine's step attends through the paged decode
+    kernel: its HLO holds the kernel's custom call and no (32, 1024, 8,
+    128) view of any layer, and it fits in HBM."""
+    import re
+    compiled = _compiled_engine_step(v5e, 2)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%paged_decode_attention[.\d]* = ", text)
+    assert "[32,1024,8,128]" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"paged step temp {temp} B at 2 layers (gathering each layer's "
+          f"view, the step held 68.5 MB)")
+    assert temp < VIEW_BYTES / 2, temp

@@ -53,6 +53,22 @@ def test_page_table_matches_oracle():
         assert [list(f) for f in impl.free] == orac.free, t
 
 
+def test_read_share_counts_allocated_blocks_below_each_position():
+    """``PageTable.read_share`` against a loop over the table: allocated
+    blocks holding a slot below the lane's position, over all blocks."""
+    rng = np.random.RandomState(2)
+    page, nsh, S_cache, slots = 4, 2, 32, 5
+    impl = _table(page, 40, nsh, S_cache, slots)
+    for _ in range(60):
+        impl.ensure(rng.randint(slots), rng.randint(S_cache))
+    impl.free_slot(3)
+    for _ in range(20):
+        pos = rng.randint(0, S_cache + 8, slots)
+        want = sum(impl.table[b, j] >= 0 and j * page < pos[b]
+                   for b in range(slots) for j in range(S_cache // page))
+        assert impl.read_share(pos) == want / (slots * S_cache // page)
+
+
 def _table(page, pps, nsh, S_cache, slots):
     from repro.serving.pages import PagePlan
     S_loc = S_cache // nsh

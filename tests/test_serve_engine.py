@@ -55,6 +55,18 @@ def test_mixed_trace_completes_with_cached_programs():
         assert all(0 <= t < cfg.vocab_size for t in r.out_tokens)
 
 
+def test_engine_observes_kv_read_share_every_step():
+    """Each step observes ``serve.kv_read_share`` from the host page table
+    (``PageTable.read_share``); off the chip the step gathers each layer's
+    view, so ``serve.paged_kernel`` reads 0."""
+    cfg, eng = _setup(3)
+    m = eng.run(_trace(cfg, 5))
+    h = eng.metrics.get("serve.kv_read_share")
+    assert h.count == m["steps"]
+    assert 0 < max(h.samples) <= 1 and min(h.samples) >= 0
+    assert eng.metrics.value("serve.paged_kernel") == 0.0
+
+
 def test_greedy_outputs_are_batching_invariant():
     """Each request decoded alone (B=1) must produce the same greedy tokens
     as the continuously-batched run -- slot assignment, paging and admission
