@@ -25,9 +25,9 @@ so its joint plans are measured-sourced.
 
 ``--check-against SEED[=FRESH]`` is the CI regression gate: after the run,
 every (primitive, flow, nbytes) row *and* every named ``programs`` entry
-(the multi-op schedules plus the end-to-end ``train_step`` barrier/overlap
-pair) of the fresh bench JSON is compared against SEED and the process
-exits non-zero when any cell's best ``measured_us`` regresses beyond
+(the multi-op schedules plus the end-to-end ``train_step`` row) of the
+fresh bench JSON is compared against SEED and the process exits non-zero
+when any cell's best ``measured_us`` regresses beyond
 ``--tolerance`` (default 2x -- CPU-substrate wall times are noisy; the
 gate catches order-of-magnitude breakage, not percent drift).  Seed cells
 are lifted to the ``--floor-us`` absolute floor before the tolerance
@@ -202,8 +202,8 @@ def profile_mode(cache_dir: str, out_json: str) -> None:
         primitives.fused_kernels()
     # 5. end-to-end step accounting.  The train-step bench runs on the
     # multi-pod (2x2x2) cube, a different topology fingerprint than the
-    # ring sweep above -- tune that cube too so the step's grad-sync
-    # exposure estimates (incl. the DCN hop) price measured-sourced.
+    # ring sweep above -- tune that cube too so the step's collective
+    # estimates (incl. the DCN hop) price measured-sourced.
     from benchmarks import train_step
     pod_cube = train_step._setup_train()[1].cube
     pod_profile = tuner.tune(pod_cube, sizes=(64 * 1024, 256 * 1024,

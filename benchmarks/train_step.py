@@ -1,39 +1,18 @@
-"""End-to-end train-step benchmark: comm-visible vs comm-hidden grad sync.
+"""End-to-end train-step benchmark.
 
 The primitive sweep prices collectives in isolation; this section prices a
-whole training step (fwd -> bwd -> gradient sync -> clip -> AdamW) on the
-multi-pod CPU substrate (2 pods x 2 data x 2 model), the accounting the PIM
-methodology survey (arXiv:2205.14647) asks for.  Two variants of the same
-step run on identical params/batch:
+whole training step (fwd -> bwd -> clip -> AdamW, the gradient reductions
+inserted by ``shard_map(check_vma=True)`` autodiff) on the multi-pod CPU
+substrate (2 pods x 2 data x 2 model), the accounting the PIM methodology
+survey (arXiv:2205.14647) asks for.
 
-  barrier (comm-visible)
-      ``TrainConfig(overlap_grad_sync=False)``: backward completes, then
-      one coalesced grad-sync program executes -- every wire microsecond
-      lands on the critical path.
-
-  overlap (comm-hidden)
-      ``TrainConfig(overlap_grad_sync=True)``: reverse-layer bucket
-      programs fire *during* backward via custom_vjp hooks
-      (:mod:`repro.runtime.overlap`), so the head bucket's sync runs under
-      the remaining backward compute.
-
-Both step functions are checked bit-identical (same updated params from
-the same inputs) before timing.  Each variant contributes a row to the
-``programs`` section of the bench trajectory: ``measured_us`` is the
-median wall time per step (the regression-gate column -- on this
-substrate's in-process device threads the two fused programs wall-time
-within noise of each other, XLA CPU serializes collectives against
-compute), ``serial_est_us`` sums the step's traced grad-sync op estimates
-(all comm priced on the critical path), and ``plan_est_us`` is the
-*exposed* sync budget under the DDP exposure model (see
-:func:`_price_step`): the barrier program is fully exposed, the
-overlapped path exposes only its final bucket, so the overlapped row's
-``plan_est_us`` sits strictly below the barrier row's.  Under the tuned
-CommProfile of a ``--profile`` run both estimate columns are
-measured-sourced.  On vma-tracking jax the hook path is inert, so the two
-variants collapse to the same step -- the rows still gate wall-time
-regressions but the overlap-vs-barrier gap is only meaningful on the
-pre-vma leg.
+The step contributes one ``train_step`` row to the ``programs`` section of
+the bench trajectory: ``measured_us`` is the median wall time per step (the
+regression-gate column), ``ops`` counts the collectives the first (traced)
+call dispatched through the communicators, and ``plan_est_us`` /
+``serial_est_us`` both sum those dispatches' planner estimates (they are
+issued one by one, not as a jointly planned program).  Under the tuned
+CommProfile of a ``--profile`` run the estimates are measured-sourced.
 """
 from __future__ import annotations
 
@@ -44,11 +23,11 @@ import numpy as np
 from benchmarks._timing import bench, emit
 
 ARCH = "qwen3-1.7b"
-STEP_NAME = "train_step"      # row names: train_step_barrier/_overlap
+STEP_NAME = "train_step"
 
-# Upscale the smoke config until the pod-crossing gradient sync is a real
-# fraction of the step (~25MB of replicated gradients): at pure smoke scale
-# the sync is <1% of wall time and the overlap win drowns in step noise.
+# Upscale the smoke config until the pod-crossing gradient reductions are a
+# real fraction of the step (~25MB of replicated gradients): at pure smoke
+# scale they are <1% of wall time and drown in step noise.
 SCALE = dict(d_model=256, n_heads=8, head_dim=32, d_ff=1024, vocab_size=8192)
 
 
@@ -97,104 +76,42 @@ def _step_timer(step_fn, params, opt_state, batch):
     return call
 
 
-def _price_step(tr, cube):
-    """(ops, exposed_plan_us, serial_est_us, est_source) for one traced
-    step.  ``serial`` sums every grad-sync op's estimate (all comm priced
-    on the critical path).  ``exposed`` prices what the sync adds to the
-    step under the DDP exposure model: the barrier path's single program
-    is entirely exposed (it cannot start before the last gradient exists),
-    while the overlapped path exposes only its *final* bucket -- the one
-    whose cotangents are backward's last outputs -- because every earlier
-    bucket fires with backward compute still ahead to hide under.  Each
-    program is priced by :func:`planner.plan_program`, so under an
-    installed tuned CommProfile both columns are measured-sourced."""
-    from repro.core import planner
-    by_prog: dict[str, list] = {}
-    for e in tr.events:
-        if e.program_id and e.program_id.startswith("grad-sync"):
-            by_prog.setdefault(e.program_id, []).append(e)
-    serial_s = sum(e.seconds for evs in by_prog.values() for e in evs)
-    plans = {}
-    for pid, evs in by_prog.items():
-        plans[pid] = planner.plan_program(cube, [
-            planner.ProgramOpSpec(op_id=i, primitive=e.primitive,
-                                  dims=e.dims, payload_bytes=e.payload_bytes)
-            for i, e in enumerate(evs)])
-    sources = {p.est_source for p in plans.values()}
-    source = sources.pop() if len(sources) == 1 else ("mixed" if sources
-                                                      else "analytic")
-    # buckets are named grad-sync-b{k}; the highest k (the embedding
-    # bucket) is the one backward cannot hide.  The barrier path has one
-    # unsuffixed program, which is then also the "last" -- fully exposed.
-    exposed_s = 0.0
-    if plans:
-        last = max(plans, key=lambda pid: int(pid.rsplit("-b", 1)[1])
-                   if "-b" in pid else -1)
-        exposed_s = plans[last].seconds
-    return len(tr.events), exposed_s * 1e6, serial_s * 1e6, source
-
-
-def _assert_bit_identical(p_a, p_b):
-    import jax
-    flat_a, tdef = jax.tree.flatten(jax.device_get(p_a))
-    flat_b = tdef.flatten_up_to(jax.device_get(p_b))
-    for a, b in zip(flat_a, flat_b):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), (
-            "overlapped grad sync diverged from the barrier path")
-
-
 def train_step_bench():
-    """Emits train_step_{barrier,overlap} program rows; asserts the two
-    sync paths produce bit-identical updated params first."""
+    """Emits the ``train_step`` program row, then the
+    ``telemetry_overhead`` and ``ckpt_overlap`` rows on the same step."""
     from repro.core.comm import CommTrace
     from repro.runtime.trainer import TrainConfig, make_train_step
 
     cfg, topo = _setup_train()
     batch = _make_batch(cfg, B=8, S=64)
-    variants = {
-        "barrier": TrainConfig(overlap_grad_sync=False),
-        "overlap": TrainConfig(overlap_grad_sync=True),
-    }
-    steps = {tag: make_train_step(cfg, topo, tc)
-             for tag, tc in variants.items()}
+    tc = TrainConfig()
+    step = make_train_step(cfg, topo, tc)
 
-    # bit-identity gate: one step of each variant from identical state --
-    # this first call is also the one jax traces, so it is the call the
-    # CommTrace must wrap to see the step's comm events
-    stepped, traces = {}, {}
-    for tag, tc in variants.items():
-        params, opt_state = _fresh_state(cfg, topo, tc)
-        with CommTrace() as tr:
-            p1, _, _ = steps[tag](params, opt_state, batch)
-        stepped[tag], traces[tag] = p1, tr
-    _assert_bit_identical(stepped["barrier"], stepped["overlap"])
+    # the first call is the one jax traces, so it is the call the CommTrace
+    # must wrap to see the step's comm events
+    params, opt_state = _fresh_state(cfg, topo, tc)
+    with CommTrace() as tr:
+        step(params, opt_state, batch)
+    est_us = sum(e.seconds for e in tr.events) * 1e6
+    sources = {e.est_source for e in tr.events}
+    source = sources.pop() if len(sources) == 1 else ("mixed" if sources
+                                                      else "analytic")
 
-    rows = {}
-    for tag, tc in variants.items():
-        params, opt_state = _fresh_state(cfg, topo, tc)
-        tr = traces[tag]
-        call = _step_timer(steps[tag], params, opt_state, batch)
-        us = bench(call, warmup=2, reps=7)
-        n_ops, exposed_us, serial_us, source = _price_step(tr, topo.cube)
-        rows[tag] = {"name": f"{STEP_NAME}_{tag}", "ops": n_ops,
-                     "measured_us": round(us, 2),
-                     "plan_est_us": round(exposed_us, 3),
-                     "serial_est_us": round(serial_us, 3),
-                     "est_source": source}
-        emit(f"train_step/{ARCH}/{tag}", us,
-             f"events={n_ops};sync_exposed_us={exposed_us:.1f}"
-             f";sync_serial_us={serial_us:.1f};est_source={source}")
-    hidden = (rows["barrier"]["plan_est_us"]
-              - rows["overlap"]["plan_est_us"])
-    emit(f"train_step/{ARCH}/comm_hidden_us", hidden,
-         "barrier_exposed_minus_overlap_exposed")
+    params, opt_state = _fresh_state(cfg, topo, tc)
+    us = bench(_step_timer(step, params, opt_state, batch), warmup=2, reps=7)
+    row = {"name": STEP_NAME, "ops": len(tr.events),
+           "measured_us": round(us, 2),
+           "plan_est_us": round(est_us, 3),
+           "serial_est_us": round(est_us, 3),
+           "est_source": source}
+    emit(f"train_step/{ARCH}", us,
+         f"events={len(tr.events)};comm_est_us={est_us:.1f}"
+         f";est_source={source}")
 
-    overhead = telemetry_overhead_bench(cfg, topo, steps["barrier"],
-                                        variants["barrier"], batch,
-                                        disabled_us=rows["barrier"]
-                                        ["measured_us"])
-    ckpt = ckpt_overlap_bench(cfg, topo, variants["barrier"])
-    return [rows["barrier"], rows["overlap"], overhead, ckpt]
+    overhead = telemetry_overhead_bench(cfg, topo, step, tc, batch,
+                                        disabled_us=row["measured_us"])
+    ckpt = ckpt_overlap_bench(cfg, topo, tc)
+    return [row, overhead, ckpt]
 
 
 def ckpt_overlap_bench(cfg, topo, tc):
@@ -278,14 +195,14 @@ def ckpt_overlap_bench(cfg, topo, tc):
 
 def telemetry_overhead_bench(cfg, topo, step_fn, tc, batch, *,
                              disabled_us: float):
-    """``telemetry_overhead`` row: the barrier step re-timed with metrics
+    """``telemetry_overhead`` row: the train step re-timed with metrics
     enabled and a Tracer active, including the per-step bookkeeping
     ``Trainer.run`` does on the enabled path (span + counter + histogram).
     ``measured_us`` is the enabled step; ``plan_est_us``/``serial_est_us``
-    carry the disabled baseline (the already-gated ``train_step_barrier``
-    cell), so the gate tracks the enabled path and the ratio of the two
-    columns is the relative overhead -- "disabled within noise of the
-    pre-PR step" is enforced by the unchanged ``train_step_barrier`` row.
+    carry the disabled baseline (the already-gated ``train_step`` cell),
+    so the gate tracks the enabled path and the ratio of the two columns
+    is the relative overhead -- "disabled within noise of the pre-PR step"
+    is enforced by the ``train_step`` row.
 
     The Tracer sees no CommEvents here (the step is already compiled;
     dispatch happens at trace time), so this prices exactly the

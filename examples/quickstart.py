@@ -7,11 +7,9 @@ stages, lets planner-driven ``algorithm="auto"`` dispatch pick the
 §IX-A hierarchical flow on a pod-crossing all-reduce -- with every dispatch
 observed by a :class:`CommTrace` -- and records a deferred ``cube.program()``
 whose lowering fuses a reduce_scatter+all_gather chain into one all_reduce.
-Section 9 walks the backward-overlapped gradient sync: reverse-layer bucket
-programs fired inside backward via custom_vjp hooks, bit-identical to the
-barrier path.  Section 10 runs the continuous-batching serve engine
-(paged KV cache + one recorded CommProgram per decode step) through an
-admit -> prefill -> decode -> evict request lifecycle.  Section 11 races
+Section 9 runs the continuous-batching serve engine (paged KV cache + one
+recorded CommProgram per decode step) through an admit -> prefill ->
+decode -> evict request lifecycle.  Section 10 races
 the collective-fused kernels (repro.kernels.collective): a measured
 profile steers a recorded program's all_gather onto the ring_fused flow,
 bit-identically.
@@ -169,7 +167,7 @@ print("auto dispatch priced from the measured CommProfile "
 #    seconds-vs-serial budget from those measurements: the printed plan
 #    carries est_source=measured, closing the loop the per-op models left
 #    open.  Structurally identical recordings reuse one cached lowered
-#    schedule (the trainer's per-step grad sync rides this cache).
+#    schedule (the serve engine's per-step program rides this cache).
 from repro.core.program import LOWER_STATS  # noqa: E402
 
 print("overlap factors:",
@@ -196,72 +194,7 @@ print(f"overlap-aware plan: {plan.seconds*1e6:.1f}us vs serial "
       f"{plan.serial_seconds*1e6:.1f}us (est_source={plan.est_source}); "
       "re-recording reused the cached lowered program")
 
-# 9. backward-overlapped gradient sync: the trainer's barrier path runs
-#    backward to completion and then executes ONE coalesced grad-sync
-#    program -- every wire microsecond exposed.  The overlapped path
-#    (repro.runtime.overlap) partitions the replicated gradients into
-#    reverse-layer buckets and fires each bucket's program *inside*
-#    backward via an identity custom_vjp hook: the loss head's gradients
-#    are backward's first outputs, so its bucket (grad-sync-b0) dispatches
-#    while the rest of backward still computes, hiding its wire time.
-#    Grads stay bit-identical to the barrier path.  On vma-tracking jax
-#    autodiff inserts (and interleaves) the reductions itself, so the
-#    hooks are inert there and the two paths coincide.
-from repro import compat  # noqa: E402
-from repro.runtime.overlap import with_backward_bucket_sync  # noqa: E402
-from repro.runtime.trainer import sync_replicated_grads  # noqa: E402
-
-tree = {"embed": jnp.ones((8, 4)),                 # sharded: no sync needed
-        "units": {"w": jnp.ones((2, 16))},         # replicated trunk
-        "lm_head": jnp.ones((4, 16))}              # replicated loss head
-tspecs = {"embed": P(("pod", "dp", "tp"), None),
-          "units": {"w": P()}, "lm_head": P()}
-
-def toy_loss(p, b):
-    # consume groups in forward order (embed -> trunk -> head), like a
-    # real model: backward then produces the head gradients first
-    h = jnp.sum(jnp.square(p["embed"])) + 0.0 * b
-    h = h + jnp.sum(jnp.square(p["units"]["w"]))
-    h = h + jnp.sum(jnp.square(p["lm_head"]))
-    return h, {}
-
-hooked_loss = with_backward_bucket_sync(toy_loss, tspecs, prod)
-
-def overlapped_grads(p, b):
-    (_, _), grads = jax.value_and_grad(hooked_loss, has_aux=True)(p, b)
-    return grads                       # synced during backward, per bucket
-
-def barrier_grads(p, b):
-    (_, _), grads = jax.value_and_grad(toy_loss, has_aux=True)(p, b)
-    return sync_replicated_grads(grads, tspecs, prod)
-
-b9 = jnp.float32(1.0)
-with CommTrace() as btrace:
-    g_ov = jax.jit(shard_map(
-        overlapped_grads, mesh=prod.mesh, in_specs=(tspecs, P()),
-        out_specs=tspecs, check_vma=False))(tree, b9)
-g_bar = jax.jit(shard_map(
-    barrier_grads, mesh=prod.mesh, in_specs=(tspecs, P()),
-    out_specs=tspecs, check_vma=False))(tree, b9)
-
-bucket_order = [ev.program_id for ev in btrace.events
-                if ev.program_id and ev.program_id.startswith("grad-sync-b")]
-overlap_summary = btrace.summary()
-print("backward-overlap trace summary:", overlap_summary)
-print("bucket dispatch order during backward:", bucket_order)
-
-flat_bar, tdef9 = jax.tree.flatten(jax.device_get(g_bar))
-for want, got in zip(flat_bar, tdef9.flatten_up_to(jax.device_get(g_ov))):
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-if not compat.HAS_VMA:
-    # head bucket first, trunk second; the fully-sharded embed leaf never
-    # records a program at all
-    assert bucket_order == ["grad-sync-b0", "grad-sync-b1"]
-    assert overlap_summary["programs"] == ["grad-sync-b0", "grad-sync-b1"]
-print("backward-overlapped sync: bucket programs fired in reverse-layer "
-      "order during backward, bit-identical to the barrier sync")
-
-# 10. production decode serving (repro.serving): a paged/block KV cache --
+# 9. production decode serving (repro.serving): a paged/block KV cache --
 #     per-shard page pools, a per-request page table, cross-cube page
 #     motion as rooted scatter/gather -- under a continuous-batching
 #     engine.  One request's lifecycle: it ADMITS from the arrival queue
@@ -304,7 +237,7 @@ print(f"served {len(serve_metrics['finished'])} requests in "
       f"{serve_metrics['tokens_per_s']:.0f} tok/s; the per-step program "
       "lowered once and hit the fingerprint cache every step after")
 
-# 11. collective-fused kernels (repro.kernels.collective): ring-rotation
+# 10. collective-fused kernels (repro.kernels.collective): ring-rotation
 #     flows that weave the collective *through* compute -- ring attention,
 #     gather prologues, reduce-scatter epilogues -- registered in the same
 #     algorithm registry as the Table II stages, so they trace, price, and
@@ -350,7 +283,7 @@ print("measured profile steered the recorded program onto the fused ring "
       f"flow (est {fest.seconds * 1e6:.2f}us measured), bit-identical "
       "to the Table II gather")
 
-# 12. unified telemetry (repro.telemetry): one Tracer captures a span
+# 11. unified telemetry (repro.telemetry): one Tracer captures a span
 #     timeline across a train step and the serving engine.  While the
 #     tracer is active it sits on the comm trace stack, so every live
 #     CommEvent becomes an instant inside whatever span is open --
@@ -367,20 +300,33 @@ import time  # noqa: E402
 import warnings  # noqa: E402
 
 from repro import telemetry  # noqa: E402
+from repro.compat import replicated_psum  # noqa: E402
+
+
+def toy_grads(p, rows):
+    # each device's partial loss meets the replicated leaf "w"
+    def loss(q):
+        return replicated_psum(jnp.sum(rows @ q["w"]), prod.dim_names)
+    _, grads = jax.value_and_grad(loss)(p)
+    return grads
+
 
 engine.reset_metrics()                   # warmup boundary: fresh registry
-steps_before12 = engine.step_idx         # run() reports cumulative steps
+steps_before11 = engine.step_idx         # run() reports cumulative steps
 telemetry.enable_metrics()
 with telemetry.Tracer() as tracer:
     with tracer.span("train.step", cat="wall"):
-        # fresh jit -> retrace -> the step's grad-sync dispatches land as
-        # instants inside the train.step envelope
+        # a toy gradient the way the train step takes it: value_and_grad
+        # under shard_map(check_vma=True), whose autodiff sums the
+        # replicated leaf's gradient over the cube -- no sync call
         jax.block_until_ready(jax.jit(shard_map(
-            barrier_grads, mesh=prod.mesh, in_specs=(tspecs, P()),
-            out_specs=tspecs, check_vma=False))(tree, b9))
-    req12 = Request(rid=9, prompt=[6, 2, 8, 3], max_new=3,
+            toy_grads, mesh=prod.mesh,
+            in_specs=({"w": P()}, P(("pod", "dp", "tp"), None)),
+            out_specs={"w": P()}, check_vma=True))(
+                {"w": jnp.ones((16,))}, jnp.ones((8, 16))))
+    req11 = Request(rid=9, prompt=[6, 2, 8, 3], max_new=3,
                     arrival=engine.step_idx)
-    serve12 = engine.run([req12])        # serve.step spans + children
+    serve11 = engine.run([req11])        # serve.step spans + children
 telemetry.disable_metrics()
 
 chrome = json.loads(tracer.chrome_trace_json())   # Perfetto-loadable
@@ -395,11 +341,11 @@ assert all("est_source" in e["args"] and "fused_from" in e["args"]
 assert any(e.get("name") == "program.lower_cache_hit" for e in evs), \
     "warm-cache lowerings annotate the timeline"
 snap = telemetry.REGISTRY.snapshot()
-steps12 = serve12["steps"] - steps_before12
+steps11 = serve11["steps"] - steps_before11
 assert telemetry.REGISTRY.value("comm.dispatches") > 0
-assert telemetry.REGISTRY.value("program.lower_cache_hits") >= steps12
-assert engine.metrics.value("serve.steps") == steps12
-assert serve12["p50_token_s"] == engine.metrics.quantile(
+assert telemetry.REGISTRY.value("program.lower_cache_hits") >= steps11
+assert engine.metrics.value("serve.steps") == steps11
+assert serve11["p50_token_s"] == engine.metrics.quantile(
     "serve.token_seconds", 0.50)
 print(f"telemetry: {len(serve_spans)} serve.step spans, "
       f"{len(prog_children)} per-op comm instants with provenance, "
@@ -407,17 +353,17 @@ print(f"telemetry: {len(serve_spans)} serve.step spans, "
       "lower-cache-hit marks; engine registry is the measurement path")
 
 mon = telemetry.DriftMonitor(min_samples=1)     # judge on first residual
-t12 = time.perf_counter()
+t11 = time.perf_counter()
 with install_profile(fused_prof):
     jax.block_until_ready(jax.jit(shard_map(
         lambda v: flow_lowered.execute(v), mesh=cube.mesh,
         in_specs=P("x", "y", "z", None),
         out_specs=P("x", "y", None, None), check_vma=False))(fx))
-wall12 = time.perf_counter() - t12
+wall11 = time.perf_counter() - t11
 with warnings.catch_warnings(record=True) as wlist:
     warnings.simplefilter("always")
     for ev in ftrace.events:     # measured-sourced, priced ~0 by fused_prof
-        mon.observe_event(ev, measured_s=wall12)
+        mon.observe_event(ev, measured_s=wall11)
 stale = [w.message for w in wlist
          if isinstance(w.message, telemetry.ProfileStalenessWarning)]
 assert len(stale) == 1, "exactly one structured warning per stale key"
@@ -428,7 +374,7 @@ print(f"drift monitor flagged ({sw.flow}, {sw.stage}, {sw.domain}): "
       f"median meas_over_est={sw.median:.3g} outside "
       f"[{sw.band[0]:g}, {sw.band[1]:g}] -- {sw.recipe}")
 
-# 13. elastic checkpointing (repro.checkpoint): save from the 2x2x2 cube
+# 12. elastic checkpointing (repro.checkpoint): save from the 2x2x2 cube
 #     -- one recorded rooted-gather program per section; the second save's
 #     structural fingerprint matches the first, so it hits the lower cache
 #     -- then restore the same checkpoint onto a 1-D ring of the same 8
@@ -466,9 +412,9 @@ ckpt_summary = ckpt_trace.summary()
 assert "ckpt-restore-params" in ckpt_summary["programs"]
 assert restored["w"].sharding.spec == P("r", None)
 
-fwd13 = jax.jit(lambda t: t["w"] @ t["b"])
-np.testing.assert_array_equal(np.asarray(fwd13(restored)),
-                              np.asarray(fwd13(host_w)))
+fwd12 = jax.jit(lambda t: t["w"] @ t["b"])
+np.testing.assert_array_equal(np.asarray(fwd12(restored)),
+                              np.asarray(fwd12(host_w)))
 shutil.rmtree(ckpt_dir)
 print("elastic restore: saved on {x,y,z}=2x2x2, restored onto {r}=8 via "
       f"a planned scatter program ({ckpt_cache_hits} save lower-cache "
@@ -491,9 +437,6 @@ if os.environ.get("QUICKSTART_SUMMARY"):
                        "serial_seconds": plan.serial_seconds,
                        "est_source": plan.est_source,
                        "order": list(plan.order)},
-                   "backward_overlap": {
-                       "bucket_order": bucket_order,
-                       "summary": overlap_summary},
                    "fused_kernels": {
                        "summary": fused_summary,
                        "flow": ftrace.events[0].flow,

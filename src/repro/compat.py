@@ -18,9 +18,6 @@ Exports:
   axis_index / dynamic_update_slice / dynamic_slice / fori_loop
       Re-exports of the non-collective lax helpers the app layer uses, so
       application code never imports ``jax.lax`` directly (grep-enforced).
-  HAS_VMA
-      Always True: the supported jax tracks varying axes in avals. Kept
-      for the pre-vma gradient-sync path that still branches on it.
 
 Importing it also gives the program's spans
 (``repro.telemetry.spans.maybe_span``) the JAX profiler as a sink: while
@@ -51,9 +48,6 @@ def make_mesh(shape, axes, *, devices=None):
 
 
 # ---------------------------------------------------------- varying axes
-HAS_VMA: bool = True
-
-
 def vma_of(x) -> frozenset:
     """The varying-axes set of ``x`` (empty outside shard_map)."""
     return jax.typeof(x).vma

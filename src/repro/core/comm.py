@@ -483,50 +483,6 @@ class Communicator:
             return x
         return self._dispatch("all_reduce", x, algorithm=algorithm, op=op)
 
-    def all_reduce_with_error(self, x: Array, *, error: Array | None = None,
-                              block: int = 256) -> tuple[Array, Array]:
-        """§V-C compressed (int8 DCN hop) additive all-reduce that also
-        returns the local quantization error, for callers that persist an
-        error-feedback buffer across steps (``runtime.trainer``).
-
-        ``error`` is the previous step's returned error (replicated within
-        the fast/ICI group, per-pod values).  It is folded in scaled by
-        1/|ICI|: the fast-domain reduce inside the flow sums the |ICI|
-        replicas back to exactly one correction per pod.
-
-        Always dispatches eagerly (even inside a program recording scope:
-        the two-output flow has no registry body) and records a
-        ``compressed`` CommEvent like the single-output registry algorithm.
-        """
-        from repro.core import compress
-        if not self.slow_dims:
-            raise ValueError(
-                "all_reduce_with_error needs a DCN-crossing group; "
-                f"{self.dims} is entirely intra-pod")
-        if error is not None:
-            gf = self.cube.group_size(self.fast_dims) if self.fast_dims \
-                else 1
-            x = x + error / gf
-        payload = _payload_bytes(x)
-        if _TRACES or _telemetry.enabled():
-            est = planner.estimate(self.cube, "all_reduce", self.dims,
-                                   payload, algorithm="compressed",
-                                   block=block)
-            _telemetry.inc("comm.dispatches")
-            _telemetry.inc(f"comm.est_source.{est.est_source}")
-        if _TRACES:
-            _emit(CommEvent(
-                primitive="all_reduce", bitmap=self.bitmap, dims=self.dims,
-                algorithm="compressed", flow="compressed", stage="cm",
-                group_size=self.group_size,
-                num_instances=self.num_instances, payload_bytes=payload,
-                ici_bytes=est.ici_bytes, dcn_bytes=est.dcn_bytes,
-                seconds=est.seconds, est_source=est.est_source))
-        with _scoped(scope_name("all_reduce", self.bitmap, "compressed",
-                                payload)):
-            return compress.compressed_pod_all_reduce(
-                x, self.cube, self.fast_dims, self.slow_dims, block=block)
-
     # ------------------------------------------------- rooted (host) four
     def scatter(self, host_value, *, axis: int | None = None,
                 spec: tuple | None = None,

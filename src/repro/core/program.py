@@ -22,7 +22,7 @@ each call in isolation; this module adds the whole-program surface:
           (and the reverse split when the cost model strictly prefers it);
         * same-group coalescing -- independent small all-reduces on the same
           (group, op, dtype, algorithm) flatten/concat into one bucketed
-          dispatch (the trainer's ``sync_replicated_grads`` is the client);
+          dispatch;
         * joint planning -- one :func:`repro.core.planner.plan_program` pass
           estimating every op under a shared ICI/DCN budget and choosing an
           explicit interleaving order for independent ops.
@@ -41,11 +41,12 @@ Eager single-op calls remain supported -- a one-op program executes the
 identical registry body, so the conformance matrix is bit-identical through
 both paths (tests/test_program.py).
 
-Repeated recordings with identical op structure (the trainer's per-step
-gradient sync, any re-traced ``comm.program()`` scope) reuse one cached
-lowered schedule -- rewrite passes, coalescing buckets and the joint plan
-run once per structural fingerprint, not once per program instance (see
-``_LOWER_CACHE`` / ``LOWER_STATS``).
+Repeated recordings with identical op structure (the serve engine's
+per-step ``serve-step`` program, any re-traced ``comm.program()`` scope)
+reuse one cached lowered schedule -- rewrite passes, coalescing buckets and
+the joint plan run once per structural fingerprint, not once per program
+instance (see ``_LOWER_CACHE`` / ``LOWER_STATS``).  The other client is
+checkpoint resharding (``repro.checkpoint.reshard``).
 """
 from __future__ import annotations
 
@@ -73,9 +74,9 @@ _PROGRAM_IDS = itertools.count()
 # -------------------------------------------------- cross-program reuse
 # Two programs with the same *structure* (op graph, avals, input/output
 # wiring) lower to the same optimized schedule, so re-lowering every
-# instance -- the trainer records a fresh grad-sync program each traced
-# step -- redoes identical rewrite passes, bucket construction and joint
-# planning.  ``lower()`` therefore consults a cache keyed by the program's
+# instance -- the serve engine records a fresh serve-step program each
+# traced step -- redoes identical rewrite passes, bucket construction and
+# joint planning.  ``lower()`` therefore consults a cache keyed by the program's
 # structural fingerprint plus everything else that shapes the result: the
 # lowering knobs and the installed profile's content token (a plan priced
 # under one profile must not serve another).  A hit rebinds the cached
@@ -386,8 +387,9 @@ class CommProgram:
         algorithm, reducer, kwargs, SSA wiring), every value's aval, and
         the input/output declarations.  Two programs with equal
         fingerprints lower to interchangeable schedules, which is what
-        keys cross-program reuse (the trainer's per-step grad-sync records
-        fresh tracers as constants, but the structure never changes)."""
+        keys cross-program reuse (the serve engine's per-step program
+        records fresh tracers as constants, but the structure never
+        changes)."""
         blob = json.dumps({
             "avals": [(list(a.shape), np.dtype(a.dtype).str)
                       for a in self._avals],
@@ -859,11 +861,10 @@ class ProgramExecution:
     def stage(self) -> "ProgramExecution":
         """Pre-build the flattened/concatenated payload of every coalesced
         op whose inputs are already available -- the memory-side half of a
-        bucketed dispatch -- without issuing any collective.  The
-        double-buffered grad-sync pipeline
-        (:mod:`repro.runtime.overlap`) stages bucket k+1 here while bucket
-        k's wire op is still in flight; ``force`` then consumes the staged
-        payload instead of re-concatenating."""
+        bucketed dispatch -- without issuing any collective.  A pipeline
+        can stage bucket k+1 here while bucket k's wire op is still in
+        flight; ``force`` then consumes the staged payload instead of
+        re-concatenating."""
         import jax.numpy as jnp
         for op in self.lowered.ops:
             if (not op.coalesced or op.op_id in self._done

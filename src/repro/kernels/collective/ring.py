@@ -140,9 +140,8 @@ def dispatch_fused(comm, primitive, flow, x, *, payload_bytes=None,
                    **kwargs):
     """Eagerly dispatch a compute-fused registry flow with the same
     planner-estimated :class:`~repro.core.comm.CommEvent` a plain dispatch
-    emits (the ``all_reduce_with_error`` precedent: callable-carrying
-    flows cannot be recorded into a CommProgram, so they always run
-    eagerly).
+    emits. Callable-carrying flows cannot be recorded into a CommProgram,
+    so they always run eagerly.
 
     ``x`` may be a pytree (ring attention rotates the ``(k, v)`` pair);
     payload accounting sums the leaves unless ``payload_bytes`` overrides

@@ -279,8 +279,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool) -> dict:
     # ("analytic" constants vs an installed measured CommProfile)
     rec["est_sources"] = rec["comm_trace"].get("est_sources", {})
     # deferred-program reuse during this cell's trace: schedules built vs
-    # served from the cross-program lower cache (grad-sync reuse shows up
-    # here when a cell traces the same program structure more than once)
+    # served from the cross-program lower cache (reuse shows up here when
+    # a cell traces the same program structure more than once)
     rec["program_cache"] = {
         k: program_mod.LOWER_STATS[k] - lower_stats0[k]
         for k in program_mod.LOWER_STATS}

@@ -90,17 +90,8 @@ DECLARED: dict[str, tuple[str, str]] = {
                               "mode; reverse-mode AD interleaves fwd and "
                               "bwd in one computation -- bwd alone is "
                               "fwd_bwd minus fwd)"),
-    "train.sync_seconds": ("histogram", "Wall seconds of the gradient-sync "
-                           "phase (telemetry_split mode)"),
     "train.opt_seconds": ("histogram", "Wall seconds of the clip+AdamW "
                           "phase (telemetry_split mode)"),
-    "train.sync_serial_est_us": ("gauge", "Planner estimate of the step's "
-                                 "grad-sync wire time, all on the critical "
-                                 "path (us; from the traced first step)"),
-    "train.sync_exposed_est_us": ("gauge", "Planner estimate of the "
-                                  "*exposed* grad-sync wire time under "
-                                  "the overlap model: only the final "
-                                  "bucket cannot hide under backward (us)"),
     # serving/engine.py -- per-engine registry (always on)
     "serve.steps": ("counter", "Engine decode steps"),
     "serve.generated_tokens": ("counter", "Generated (post-prefill) "

@@ -31,14 +31,12 @@ lm_mod.COMPUTE_DTYPE = jnp.float32
 # value, so the strict consistency check runs on the CE loss alone.
 lm_mod.AUX_COEF = 0.0
 
-from repro import compat
 from repro.configs import get
 from repro.launch.mesh import make_mesh
 from repro.models.lm import Model
 from repro.models.params import init_params, param_specs
 from repro.models.topology import build_topology
-from repro.runtime.overlap import with_backward_bucket_sync
-from repro.runtime.trainer import input_batch_specs, sync_replicated_grads
+from repro.runtime.trainer import input_batch_specs
 
 TOL = dict(rtol=5e-2, atol=5e-3)
 
@@ -48,36 +46,15 @@ def grads_fn(cfg, topo):
     specs = param_specs(cfg, topo)
 
     def f(params, batch):
-        # vma-aware autodiff inserts every needed gradient reduction; on
-        # pre-vma jax sync_replicated_grads adds the same psums explicitly
+        # vma-aware autodiff inserts every needed gradient reduction
         (loss, metrics), grads = jax.value_and_grad(
             model.loss_shard, has_aux=True)(params, batch)
-        grads = sync_replicated_grads(grads, specs, topo.cube)
         return loss, grads
 
     bspecs = input_batch_specs(cfg, topo)
     return jax.jit(shard_map(
         f, mesh=topo.cube.mesh, in_specs=(specs, bspecs),
         out_specs=(P(), specs), check_vma=True))
-
-
-def overlapped_grads_fn(cfg, topo):
-    """Backward-overlapped sync: reverse-layer bucket programs fire inside
-    backward via custom_vjp hooks (repro.runtime.overlap).  Must produce
-    grads bit-identical to the barrier path above."""
-    model = Model(cfg, topo)
-    specs = param_specs(cfg, topo)
-    hooked = with_backward_bucket_sync(model.loss_shard, specs, topo.cube)
-
-    def f(params, batch):
-        (_, _), grads = jax.value_and_grad(hooked, has_aux=True)(
-            params, batch)
-        return grads
-
-    bspecs = input_batch_specs(cfg, topo)
-    return jax.jit(shard_map(
-        f, mesh=topo.cube.mesh, in_specs=(specs, bspecs),
-        out_specs=specs, check_vma=False))
 
 
 def make_batch(cfg, rng, B=4, S=32):
@@ -136,20 +113,8 @@ def run_case(arch, overrides):
         worst = max(worst, float(np.abs(a - b).max() / denom))
     assert worst < 5e-3, f"{arch}: worst rel grad diff {worst}"
 
-    # backward-overlapped sync must be *bit-identical* to the barrier sync
-    # (on vma jax the hook path is inert -- autodiff already interleaves
-    # the reductions -- so there is nothing distinct to compare)
-    note = ""
-    if not compat.HAS_VMA:
-        g_ov = overlapped_grads_fn(cfg, topo8)(params, batch)
-        flat_ov = list(map(np.asarray,
-                           tdef.flatten_up_to(jax.device_get(g_ov))))
-        for b, o in zip(flat8, flat_ov):
-            np.testing.assert_array_equal(b, o, err_msg=(
-                f"{arch}: overlapped grad sync diverged from barrier sync"))
-        note = " overlap-sync=bit-identical"
     print(f"ok: {arch} loss={float(loss1):.4f} "
-          f"worst-rel-grad-diff={worst:.4f}{note}")
+          f"worst-rel-grad-diff={worst:.4f}")
 
 
 def main():

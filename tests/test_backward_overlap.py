@@ -1,14 +1,14 @@
-"""Backward-overlapped gradient sync + satellites of the same PR:
+"""Program futures, staging, inter-wave pricing and two trainer/bench
+guards:
 
-* reverse-layer bucketing and in-backward hook dispatch order
-  (:mod:`repro.runtime.overlap`), bit-identical to the barrier sync;
-* double-buffered ``execute_async`` bucket staging;
 * futures resolved through rewrite provenance
   (:meth:`ProgramExecution.future_for` through the rs+ag peephole and
-  through coalescing);
+  through coalescing), and foreign or unknown handles rejected;
+* ``ProgramExecution.stage`` pre-building a coalesced bucket's payload;
 * inter-wave overlap pricing in :func:`planner.plan_program`;
 * the ``Trainer.run`` step-timing fix (block before reading the clock);
-* the bench-gate absolute floor (zero-seed rows must not fire on noise).
+* the bench gate's absolute floor and its programs section
+  (:func:`benchmarks.run.check_against`).
 """
 import json
 import time
@@ -17,143 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
-from repro.core.comm import CommTrace
-from repro.runtime.overlap import (
-    bucket_leaf_indices, sync_replicated_grads_overlapped,
-    with_backward_bucket_sync)
-from repro.runtime.trainer import replication_dims, sync_replicated_grads
 from repro.testing import substrate
-
-pre_vma = pytest.mark.skipif(
-    compat.HAS_VMA, reason="vma jax: autodiff inserts the grad reductions; "
-    "the explicit overlapped sync path is inert")
-
-
-# ------------------------------------------------------------- bucketing
-def test_bucket_leaf_indices_reverse_layer_order():
-    """Bucket 0 is the loss head (first grads out of backward), the last
-    bucket is the embeddings (last grads out); unknown groups ride with
-    the trunk."""
-    params = {
-        "embed": jnp.zeros((4, 2)),
-        "final_norm": jnp.zeros((2,)),
-        "lm_head": jnp.zeros((2, 4)),
-        "units": {"b": jnp.zeros((3,)), "w": jnp.zeros((3, 3))},
-    }
-    flat, _ = jax.tree.flatten(params)
-    # flatten order: embed=0, final_norm=1, lm_head=2, units.b=3, units.w=4
-    assert bucket_leaf_indices(params) == [[1, 2], [3, 4], [0]]
-
-    # unknown top-level keys land in the trunk bucket
-    assert bucket_leaf_indices({"mystery": jnp.zeros(2),
-                                "lm_head": jnp.zeros(2)}) == [[0], [1]]
-
-
-def _toy_setup(cube):
-    """Toy param tree on the pod cube: embed fully sharded (no sync),
-    units sharded over tp only, head/norm fully replicated."""
-    params = {
-        "embed": jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4),
-        "final_norm": jnp.arange(4, dtype=jnp.float32),
-        "lm_head": jnp.arange(4 * 2, dtype=jnp.float32).reshape(4, 2),
-        "units": {"b": jnp.arange(2, dtype=jnp.float32),
-                  "w": jnp.arange(2 * 4, dtype=jnp.float32).reshape(2, 4)},
-    }
-    d = cube.dim_names                      # ("pod", "dp", "tp")
-    specs = {
-        "embed": P(d, None),
-        "final_norm": P(),
-        "lm_head": P(None, None),
-        "units": {"b": P(d[-1]), "w": P(d[-1], None)},
-    }
-    return params, specs
-
-
-def _loss(params, batch):
-    # consume param groups in forward-production order (embed -> trunk ->
-    # head), like a real model: backward then reaches the head grads first
-    h = jnp.sum(jnp.square(params["embed"])) + 0.0 * batch.sum()
-    h = h + jnp.sum(jnp.square(params["units"]["w"]))
-    h = h + jnp.sum(jnp.square(params["units"]["b"]))
-    h = h + jnp.sum(jnp.square(params["final_norm"]))
-    h = h + jnp.sum(jnp.square(params["lm_head"]))
-    return h, {}
-
-
-@pre_vma
-def test_hooked_backward_sync_bit_identical_and_ordered(cube_pod):
-    """The custom_vjp hook path produces grads bit-identical to the
-    barrier sync, and its bucket programs are dispatched in reverse-layer
-    order during backward (head bucket first)."""
-    cube = cube_pod
-    params, specs = _toy_setup(cube)
-    batch = jnp.ones((4,), jnp.float32)
-    hooked = with_backward_bucket_sync(_loss, specs, cube)
-
-    def f_barrier(p, b):
-        (_, _), g = jax.value_and_grad(_loss, has_aux=True)(p, b)
-        return sync_replicated_grads(g, specs, cube)
-
-    def f_hooked(p, b):
-        (_, _), g = jax.value_and_grad(hooked, has_aux=True)(p, b)
-        return g
-
-    in_specs = (specs, P())
-    with CommTrace() as tr:
-        gh = jax.jit(shard_map(f_hooked, mesh=cube.mesh, in_specs=in_specs,
-                               out_specs=specs, check_vma=False)
-                     )(params, batch)
-    gb = jax.jit(shard_map(f_barrier, mesh=cube.mesh, in_specs=in_specs,
-                           out_specs=specs, check_vma=False))(params, batch)
-
-    fa, tdef = jax.tree.flatten(jax.device_get(gb))
-    fb = tdef.flatten_up_to(jax.device_get(gh))
-    for a, b in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    # dispatch order: all of bucket 0's events (head) strictly before
-    # bucket 1's (trunk); the fully-sharded embed bucket records nothing
-    pids = [e.program_id for e in tr.events
-            if e.program_id and e.program_id.startswith("grad-sync-b")]
-    assert pids, "hook path recorded no bucket programs"
-    assert set(pids) == {"grad-sync-b0", "grad-sync-b1"}
-    assert pids == sorted(pids), f"bucket dispatch out of order: {pids}"
-
-
-@pre_vma
-def test_post_backward_bucketed_dispatch_order_and_identity(cube_pod):
-    """sync_replicated_grads_overlapped (the no-hook fallback) dispatches
-    its per-bucket execute_async programs in reverse-layer bucket order
-    and matches the barrier sync bit-for-bit."""
-    cube = cube_pod
-    params, specs = _toy_setup(cube)
-
-    def f_overlapped(p):
-        return sync_replicated_grads_overlapped(p, specs, cube)
-
-    def f_barrier(p):
-        return sync_replicated_grads(p, specs, cube)
-
-    with CommTrace() as tr:
-        go = jax.jit(shard_map(f_overlapped, mesh=cube.mesh,
-                               in_specs=(specs,), out_specs=specs,
-                               check_vma=False))(params)
-    gb = jax.jit(shard_map(f_barrier, mesh=cube.mesh, in_specs=(specs,),
-                           out_specs=specs, check_vma=False))(params)
-
-    fa, tdef = jax.tree.flatten(jax.device_get(gb))
-    fb = tdef.flatten_up_to(jax.device_get(go))
-    for a, b in zip(fa, fb):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    pids = [e.program_id for e in tr.events
-            if e.program_id and e.program_id.startswith("grad-sync-b")]
-    assert pids == sorted(pids), f"bucket dispatch out of order: {pids}"
-    assert len(set(pids)) >= 2
 
 
 # ------------------------------------------- futures through rewrites
@@ -473,7 +338,7 @@ def test_check_against_gates_programs_section(tmp_path):
     from benchmarks.run import check_against
 
     def prow(us):
-        return {"name": "train_step_overlap", "ops": 3, "measured_us": us,
+        return {"name": "train_step", "ops": 3, "measured_us": us,
                 "plan_est_us": 1.0, "serial_est_us": 2.0,
                 "est_source": "measured"}
 
@@ -485,7 +350,7 @@ def test_check_against_gates_programs_section(tmp_path):
     fresh_bad.write_text(json.dumps(_bench_doc(programs=[prow(250.0)])))
     assert check_against(str(seed), str(fresh_ok), tolerance=2.0) == []
     failures = check_against(str(seed), str(fresh_bad), tolerance=2.0)
-    assert len(failures) == 1 and "train_step_overlap" in failures[0]
+    assert len(failures) == 1 and "train_step" in failures[0]
     # seeds without a programs key (older trajectory docs) still gate rows
     old = tmp_path / "old.json"
     old.write_text(json.dumps({"rows": [_row(10.0)]}))

@@ -12,7 +12,6 @@
 """
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
 from repro.core import planner
 from repro.core.collectives import APPLICABILITY, Collectives
@@ -219,24 +218,6 @@ def test_commtrace_event_accounting(cube_pod):
     assert len(outer.events) == 2
 
 
-def test_commtrace_records_gradient_sync(cube_pod):
-    """The trainer's replicated-gradient sync dispatches through the
-    communicator and is observable (pre-vma explicit path only)."""
-    from repro import compat
-    if compat.HAS_VMA:
-        pytest.skip("vma jax: gradient reductions are autodiff-inserted")
-    from repro.runtime.trainer import sync_replicated_grads
-    x = substrate.integer_payload(cube_pod, (8,), seed=2)
-    specs = {"g": P()}
-    with CommTrace() as tr:
-        got = substrate.run_per_shard(
-            cube_pod,
-            lambda v: sync_replicated_grads({"g": v}, specs, cube_pod)["g"],
-            x)
-    np.testing.assert_array_equal(got, oracles.all_reduce(x, 3, (0, 1, 2)))
-    assert [e.flow for e in tr.events] == ["hierarchical"]
-
-
 # ------------------------------------------------------- shim differential
 SHIM_CELLS = [
     ("cube_ring8", "1", "all_reduce", "pidcomm"),
@@ -337,27 +318,3 @@ def test_compressed_requires_dcn_and_add(cube_ring8):
             lambda v: cube_ring8.comm("d").all_reduce(
                 v, algorithm="compressed"),
             np.ones((8, 4), np.float32))
-
-
-def test_trainer_compress_pod_grads_flag(cube_pod):
-    """sync_replicated_grads(compress_pod=True) routes DCN-crossing
-    gradient sums through the int8 registry flow (observable in the trace);
-    fully-sharded leaves are left untouched."""
-    from repro import compat
-    if compat.HAS_VMA:
-        pytest.skip("vma jax: explicit sync path inactive")
-    from repro.runtime.trainer import sync_replicated_grads
-    x = substrate.integer_payload(cube_pod, (300,), seed=4)
-    specs = {"repl": P(), "sharded": P(("pod", "dp", "tp"))}
-
-    def per_shard(v):
-        out = sync_replicated_grads(
-            {"repl": v, "sharded": v}, specs, cube_pod, compress_pod=True)
-        # the sharded leaf has no replication axes: must come back untouched
-        return out["repl"] + 0 * out["sharded"]
-
-    with CommTrace() as tr:
-        got = substrate.run_per_shard(cube_pod, per_shard, x)
-    assert [e.flow for e in tr.events] == ["compressed"]
-    np.testing.assert_allclose(
-        got, oracles.all_reduce(x, 3, (0, 1, 2)), rtol=2e-2, atol=0.5)

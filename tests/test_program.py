@@ -7,19 +7,16 @@
   bit-identical, provenance-tagged (``fused_from``), verified in the HLO,
   and strictly cheaper in event count and estimated DCN bytes/seconds;
 * the all_reduce -> rs+ag split rewrite (forced mode);
-* same-group coalescing: the trainer's gradient sync dispatches one
-  bucketed all-reduce, bit-identical to per-leaf psums;
+* same-group coalescing: independent same-group all-reduces dispatch
+  as bucketed all-reduces, one per group and size budget;
 * joint planning (planner.plan_program): dependency-safe interleaved order
   and the shared ICI/DCN budget;
-* execute_async per-op futures with dependency-ordered dispatch;
-* the error-feedback buffer for the compressed pod hop (trainer satellite):
-  quantization-error decay vs the no-feedback flow over steps.
+* execute_async per-op futures with dependency-ordered dispatch.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
 from repro.core import planner
 from repro.core.comm import CommTrace
@@ -243,57 +240,10 @@ def test_split_all_reduce_rewrite(cube_ring8):
 
 
 # ------------------------------------------------------------- coalescing
-def test_coalesced_gradient_sync_equals_per_leaf_psums(cube_pod):
-    """Acceptance: sync_replicated_grads dispatches one coalesced bucketed
-    program, bit-identical to eager per-leaf psums."""
-    from repro import compat
-    if compat.HAS_VMA:
-        pytest.skip("vma jax: gradient reductions are autodiff-inserted")
-    from repro.runtime.trainer import sync_replicated_grads
-    specs = {"a": P(), "b": P(), "c": P(), "sharded": P(("pod", "dp", "tp"))}
-    xa = substrate.integer_payload(cube_pod, (6,), seed=1)
-    xb = substrate.integer_payload(cube_pod, (2, 5), seed=2)
-    xc = substrate.integer_payload(cube_pod, (3,), seed=3)
-    xs = substrate.integer_payload(cube_pod, (4,), seed=4)
-
-    def via_sync(a, b, c, s):
-        out = sync_replicated_grads(
-            {"a": a, "b": b, "c": c, "sharded": s}, specs, cube_pod)
-        return out["a"], out["b"], out["c"], out["sharded"]
-
-    def via_eager(a, b, c, s):
-        comm = cube_pod.comm(("pod", "dp", "tp"))
-        return (comm.all_reduce(a), comm.all_reduce(b), comm.all_reduce(c), s)
-
-    from repro.compat import shard_map
-    sp = [substrate.global_spec(cube_pod, x.ndim - 3)
-          for x in (xa, xb, xc, xs)]
-
-    def run(f, trace):
-        fn = jax.jit(shard_map(f, mesh=cube_pod.mesh, in_specs=tuple(sp),
-                               out_specs=tuple(sp), check_vma=False))
-        with trace:
-            return [np.asarray(r) for r in fn(xa, xb, xc, xs)]
-
-    coal_tr, eager_tr = CommTrace(), CommTrace()
-    got = run(via_sync, coal_tr)
-    want = run(via_eager, eager_tr)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(g, w)          # bit-identical
-    np.testing.assert_array_equal(got[0], oracles.all_reduce(xa, 3,
-                                                             (0, 1, 2)))
-    # three per-leaf dispatches collapse into one bucketed dispatch
-    assert len(eager_tr.events) == 3 and len(coal_tr.events) == 1
-    [ev] = coal_tr.events
-    assert len(ev.fused_from) == 3
-    assert ev.payload_bytes == sum(e.payload_bytes for e in eager_tr.events)
-    assert ev.flow == "hierarchical"                 # planner's pod pick
-
-
 def test_multiple_coalesce_buckets_all_survive(cube_pod):
     """Regression: several distinct-group buckets in one program must each
-    emit their own coalesced op (the trainer records a mixed-dims program,
-    one group per replication pattern)."""
+    emit their own coalesced op (a mixed-dims program, one group per
+    replication pattern)."""
     prog = cube_pod.program()
     groups = [("pod",), ("pod", "tp"), ("pod", "dp", "tp")]
     vals = []
@@ -439,80 +389,6 @@ def test_execute_async_futures(cube_ring8):
 
     got = substrate.run_per_shard(cube_ring8, per_shard, x)
     np.testing.assert_array_equal(got, oracles.all_reduce(x, 1, (0,)))
-
-
-# -------------------------------------------- error feedback (satellite)
-def test_error_feedback_reduces_accumulated_error(cube_pod):
-    """ROADMAP open item: persisting the compressed hop's quantization error
-    across steps (error feedback) keeps the accumulated gradient-sum error
-    bounded, while the no-feedback flow drifts linearly."""
-    from repro import compat
-    if compat.HAS_VMA:
-        pytest.skip("vma jax: explicit sync path inactive")
-    from repro.compat import shard_map
-    from repro.runtime.trainer import sync_replicated_grads
-
-    n = 2048
-    rng = np.random.RandomState(0)
-    x = (rng.randn(8, n) * 0.01).astype(np.float32)   # one row per device
-    exact = x.sum(0)
-    specs = {"g": P()}                               # logically replicated
-    gspec = P(("pod", "dp", "tp"), None)
-    efspec = P("pod", None, None)
-
-    def step_ef(g, ef):
-        out, new_ef = sync_replicated_grads(
-            {"g": g}, specs, cube_pod, compress_pod=True, ef={"0": ef})
-        return out["g"], new_ef["0"]
-
-    def step_plain(g):
-        return sync_replicated_grads({"g": g}, specs, cube_pod,
-                                     compress_pod=True)["g"]
-
-    fn_ef = jax.jit(shard_map(step_ef, mesh=cube_pod.mesh,
-                              in_specs=(gspec, efspec),
-                              out_specs=(gspec, efspec), check_vma=False))
-    fn_plain = jax.jit(shard_map(step_plain, mesh=cube_pod.mesh,
-                                 in_specs=(gspec,), out_specs=gspec,
-                                 check_vma=False))
-
-    steps = 8
-    ef = jnp.zeros((2, 1, n), jnp.float32)
-    acc_ef = np.zeros(n, np.float64)
-    acc_plain = np.zeros(n, np.float64)
-    with CommTrace() as tr:
-        for _ in range(steps):
-            out, ef = fn_ef(x, ef)
-            acc_ef += np.asarray(out)[0].astype(np.float64)
-            acc_plain += np.asarray(fn_plain(x))[0].astype(np.float64)
-    want = steps * exact.astype(np.float64)
-    err_ef = np.abs(acc_ef - want).max()
-    err_plain = np.abs(acc_plain - want).max()
-    assert err_plain > 0                             # compression is lossy
-    assert err_ef < 0.5 * err_plain                  # feedback decays it
-    # both paths dispatch the compressed flow (observable provenance)
-    assert {e.flow for e in tr.events} == {"compressed"}
-
-
-def test_error_feedback_optstate_plumbing(cube_pod):
-    """init/spec helpers agree: buffers exist exactly for DCN-replicated
-    leaves, shaped (n_pods, *leaf) and pod-sharded."""
-    from repro import compat
-    from repro.runtime.trainer import (
-        TrainConfig, init_error_feedback, use_error_feedback)
-    params = {"norm": np.zeros((6,), np.float32),
-              "w": np.zeros((8, 3), np.float32)}
-    specs = {"norm": P(), "w": P(("pod", "dp", "tp"))}
-    ef = init_error_feedback(params, specs, cube_pod)
-    assert set(ef) == {"0"}                          # "norm" flattens first
-    assert ef["0"].shape == (2, 6)
-    tc = TrainConfig(compress_pod_grads=True)
-    assert tc.error_feedback                         # default on
-    if not compat.HAS_VMA:
-        assert use_error_feedback(tc, cube_pod)
-    ring = substrate.fake_cube((8,), ("d",), {"d": 8})
-    assert not use_error_feedback(tc, ring)          # no DCN: nothing to do
-    assert not use_error_feedback(TrainConfig(), cube_pod)
 
 
 # -------------------------------------------------- all_to_all chain merge

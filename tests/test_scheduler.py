@@ -22,9 +22,7 @@ Cross-program reuse (``repro.core.program`` lower cache):
   * structurally identical programs lower once (observable via
     ``LOWER_STATS``) and execute bit-identically through the cached
     schedule;
-  * structure, lowering knobs and installed profile all key the cache;
-  * the trainer's repeated grad-sync recordings strictly reduce lowering
-    work while ``parallel_check``-style gradient sums stay exact.
+  * structure, lowering knobs and installed profile all key the cache.
 """
 import json
 
@@ -32,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
 from repro.core import planner
 from repro.core import program as program_mod
@@ -401,48 +398,6 @@ def test_lower_cache_keys_structure_knobs_and_profile(cube_ring8):
     # same structure+knobs+profile again: hit
     _twin_program(cube_ring8).lower()
     assert program_mod.LOWER_STATS["cache_hits"] - s0["cache_hits"] == 1
-
-
-def test_trainer_grad_sync_reuses_lowered_program(cube_pod):
-    """The ROADMAP's named rewrite: repeated grad-sync recordings (one per
-    trace) strictly reduce lowering work via the cross-program cache while
-    the synced gradients stay exact."""
-    from repro import compat
-    if compat.HAS_VMA:
-        pytest.skip("vma jax: gradient reductions are autodiff-inserted")
-    from repro.compat import shard_map
-    from repro.runtime.trainer import sync_replicated_grads
-
-    specs = {"a": P(), "b": P(), "sharded": P(("pod", "dp", "tp"))}
-    xa = substrate.integer_payload(cube_pod, (6,), seed=41)
-    xb = substrate.integer_payload(cube_pod, (2, 5), seed=42)
-    xs = substrate.integer_payload(cube_pod, (4,), seed=43)
-    sp = [substrate.global_spec(cube_pod, x.ndim - 3) for x in (xa, xb, xs)]
-
-    def run_once():
-        def step(a, b, s):
-            out = sync_replicated_grads({"a": a, "b": b, "sharded": s},
-                                        specs, cube_pod)
-            return out["a"], out["b"], out["sharded"]
-        fn = jax.jit(shard_map(step, mesh=cube_pod.mesh,
-                               in_specs=tuple(sp), out_specs=tuple(sp),
-                               check_vma=False))
-        return [np.asarray(r) for r in fn(xa, xb, xs)]
-
-    s0 = dict(program_mod.LOWER_STATS)
-    first = run_once()
-    second = run_once()                  # fresh trace -> fresh recording
-    d = {k: program_mod.LOWER_STATS[k] - s0[k]
-         for k in program_mod.LOWER_STATS}
-    assert d["lowered"] == 1             # one schedule built...
-    assert d["cache_hits"] >= 1          # ...every re-trace reuses it
-    for g, w in zip(first, second):
-        np.testing.assert_array_equal(g, w)
-    np.testing.assert_array_equal(
-        first[0], oracles.all_reduce(xa, 3, (0, 1, 2)))
-    np.testing.assert_array_equal(
-        first[1], oracles.all_reduce(xb, 3, (0, 1, 2)))
-    np.testing.assert_array_equal(first[2], xs)      # sharded: untouched
 
 
 # ------------------------------------------------- bench-regression gate

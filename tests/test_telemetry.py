@@ -16,7 +16,6 @@ through program lowering, the trainer and the serving engine.
   engine steps, a train step and a garbage collection holds the catalogue's
   spans, nested; each collective's device ops carry the planner's key.
 """
-import dataclasses
 import gc
 import json
 import time
@@ -389,19 +388,11 @@ def test_trainer_telemetry_split_phases():
         telemetry.disable_metrics()
     reg = telemetry.REGISTRY
     for name in ("train.fwd_seconds", "train.fwd_bwd_seconds",
-                 "train.sync_seconds", "train.opt_seconds"):
+                 "train.opt_seconds"):
         assert reg.get(name).count == 2, name
     # phase metrics still produce a full history row
     assert np.isfinite(hist[-1]["loss"])
     assert np.isfinite(hist[-1]["grad_norm"])
-
-
-def test_split_step_rejects_compressed_path():
-    from repro.runtime.trainer import make_split_train_step
-    cfg, topo, tc, *_ = _setup_train()
-    tc = dataclasses.replace(tc, compress_pod_grads=True)
-    with pytest.raises(ValueError, match="plain gradient-sync"):
-        make_split_train_step(cfg, topo, tc)
 
 
 # ------------------------------------------------------ profiler sink
